@@ -212,8 +212,9 @@ def set_sig_backend(name: str | None) -> None:
     unavailable), ``fallback``/``pure-python`` force the pure-Python
     tier, ``device`` routes BATCHES through the JAX mesh MSM (serial
     verifies keep the auto ladder — the device path only pays off at
-    window sizes).  Unknown names raise (a typo must not silently
-    change the validation cost model)."""
+    window sizes) and raises, never degrades, when no device is usable.
+    Unknown names raise (a typo must not silently change the validation
+    cost model)."""
     global _sig_backend, _resolved
     if name in (None, "", "auto"):
         _sig_backend = None
@@ -496,18 +497,11 @@ def _batch_worker():
     stronger than 'batch TRUE implies serial TRUE')."""
     b = backend()
     if b == "device":
-        try:
-            from p1_tpu.hashx import ed25519_msm
+        # An explicit device pin never degrades: with no usable JAX
+        # device the import or the mesh raises, and the caller sees it.
+        from p1_tpu.hashx import ed25519_msm
 
-            return "device", ed25519_msm.verify_batch_device
-        except Exception as exc:  # jax missing/misconfigured: degrade
-            log.warning(
-                "device signature backend unavailable (%s); using the "
-                "host ladder for this process",
-                exc,
-            )
-            set_sig_backend(None)
-            b = backend()
+        return "device", ed25519_msm.verify_batch_device
     if b == "cryptography":
         return "cryptography", _verify_chunk
     if b == "native":

@@ -65,7 +65,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from p1_tpu.core import _ed25519 as _py
-from p1_tpu.hashx.sharded import AXIS, _SHARD_MAP_KW, _shard_map, make_mesh
+from p1_tpu.hashx import configure_compile_cache
+from p1_tpu.hashx.sharded import AXIS, make_mesh
 
 _U32 = jnp.uint32
 
@@ -366,13 +367,15 @@ def _jit_gate_msm(mesh, per_device: int):
     addition is the cross-chip reduction, so it rides home as D tiny
     arrays rather than a collective)."""
 
+    configure_compile_cache()
+
     @jax.jit
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),
-        **{_SHARD_MAP_KW: False},
+        check_vma=False,
         # check_vma off: the scan carries mix replicated constants
         # (q digits, curve constants) into varying shard data and the
         # varying-manual-axes checker wants per-op pcasts through the
@@ -407,16 +410,9 @@ def _decode_point(arr):
     return tuple(fe_to_int(np.asarray(arr)[i]) for i in range(4))
 
 
-class DeviceUnavailable(RuntimeError):
-    """No usable mesh (jax missing devices) — callers degrade to host."""
-
-
 @functools.lru_cache(maxsize=4)
 def _default_mesh(n_devices: int | None = None):
-    try:
-        return make_mesh(n_devices)
-    except Exception as exc:  # no devices / misconfigured platform
-        raise DeviceUnavailable(str(exc)) from exc
+    return make_mesh(n_devices)
 
 
 def verify_batch_device(triples, mesh=None, n_devices: int | None = None) -> bool:

@@ -39,27 +39,6 @@ from p1_tpu.hashx.jax_sha256 import default_unroll, search_step
 _U32 = jnp.uint32
 AXIS = "chips"
 
-# shard_map moved to the jax top level (and check_rep became check_vma,
-# with lax.pcast the promotion API) in newer JAX; resolve whichever this
-# environment carries so the mesh backend runs on both sides of the move.
-if hasattr(jax, "shard_map"):
-    _SHARD_MAP_KW = "check_vma"
-    _shard_map = jax.shard_map
-else:  # pre-move JAX: experimental module, check_rep, no pcast
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = "check_rep"
-
-
-def _pcast_varying(x, axis):
-    """``lax.pcast(x, axis, to="varying")`` where it exists, identity
-    where the old check_rep machinery infers replication itself."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis)
-    return x
-
 
 def make_mesh(
     n_devices: int | None = None, platform: str | None = None
@@ -81,6 +60,7 @@ def jit_sharded_step(
     batch_per_device: int,
     unroll: int | None = None,
     kernel: str = "xla",
+    interpret: bool = False,
 ) -> StepFn:
     """Jitted sharded step closed over mesh + per-device batch.
 
@@ -93,7 +73,8 @@ def jit_sharded_step(
     ``kernel`` picks the per-device search body: ``"xla"`` (the jax_sha256
     formulation — also what CPU validation meshes run) or ``"pallas"``
     (the Mosaic kernel of pallas_backend inside shard_map, so every chip
-    of a real TPU mesh mines at the single-chip kernel rate, docs/PERF.md).
+    of a real TPU mesh mines at the single-chip kernel rate, docs/PERF.md;
+    ``interpret=True`` runs it in the Pallas interpreter on a CPU mesh).
     """
     n = mesh.devices.size
     span = n * batch_per_device
@@ -103,10 +84,14 @@ def jit_sharded_step(
     if kernel == "pallas":
         from p1_tpu.hashx.pallas_backend import pallas_search_fn
 
+        if not interpret and not is_tpu_platform(platform):
+            raise RuntimeError(
+                f"the pallas body needs a TPU mesh, and this mesh is "
+                f"{platform!r}; pass interpret=True to run it in the "
+                "Pallas interpreter"
+            )
         device_search = pallas_search_fn(
-            batch_per_device,
-            interpret=not is_tpu_platform(platform),
-            unroll=unroll,
+            batch_per_device, interpret=interpret, unroll=unroll
         )
     elif kernel == "xla":
         if unroll is None:
@@ -128,11 +113,11 @@ def jit_sharded_step(
 
     @jax.jit
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(), P()),
         out_specs=P(),
-        **{_SHARD_MAP_KW: check_vma},
+        check_vma=check_vma,
     )
     def step(midstate, tail, target, nonce_base):
         d = lax.axis_index(AXIS).astype(_U32)
@@ -143,7 +128,8 @@ def jit_sharded_step(
             # match, or the fori_loop carry in the compression rejects the
             # mixed types.
             midstate, tail, target = (
-                _pcast_varying(x, AXIS) for x in (midstate, tail, target)
+                lax.pcast(x, AXIS, to="varying")
+                for x in (midstate, tail, target)
             )
         off = device_search(midstate, tail, target, base)
         hit = off < _U32(batch_per_device)
@@ -170,14 +156,15 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
         platform: str | None = None,
         unroll: int | None = None,
         kernel: str | None = None,
+        interpret: bool = False,
     ):
         self.mesh = make_mesh(n_devices, platform)
         mesh_platform = self.mesh.devices.flat[0].platform
         if kernel is None:
-            # Real TPU chips run the Mosaic kernel (7x the XLA formulation,
-            # docs/PERF.md); CPU validation meshes keep the XLA body — the
-            # interpreted Pallas kernel is a correctness tool, too slow to
-            # be the default 8-virtual-device path.
+            # TPU meshes run the Mosaic kernel; CPU validation meshes run
+            # the XLA body.  A choice made from the mesh's platform, not a
+            # fallback: the Pallas body on a CPU mesh is asked for
+            # explicitly (kernel="pallas", interpret=True).
             kernel = "pallas" if is_tpu_platform(mesh_platform) else "xla"
         if batch is None:
             if kernel == "pallas" and is_tpu_platform(mesh_platform):
@@ -217,10 +204,13 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
                 f"batch {batch}) must stay below uint32 nonce space"
             )
         self.unroll = unroll
+        self.interpret = interpret
         # No opening ramp: the per-device batch is baked into the mesh
         # program, and a v5e-8 step is already granular enough per chip.
         self.ramp_floor = None
 
     def _make_step(self, span: int) -> StepFn:
         assert span == self.step_span, "sharded step span is fixed"
-        return jit_sharded_step(self.mesh, self.batch, self.unroll, self.kernel)
+        return jit_sharded_step(
+            self.mesh, self.batch, self.unroll, self.kernel, self.interpret
+        )

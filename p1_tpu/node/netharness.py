@@ -18,6 +18,9 @@ import time
 
 from p1_tpu.node.byzantine import byzantine_actor, new_stats
 
+#: Hash backends that run on a JAX device.
+DEVICE_BACKENDS = frozenset({"jax", "tpu", "sharded"})
+
 
 async def inject_txs(
     ports, keys, difficulty, deadline, rate, retarget=None
@@ -113,6 +116,19 @@ def run_net(args) -> int:
 
     from p1_tpu.core.keys import Keypair
     from p1_tpu.core.retarget import RetargetRule
+
+    if args.backend in DEVICE_BACKENDS and args.nodes > 1:
+        # Every child would open the one chip; a chip serves one process
+        # at a time, so the second child fails or hangs.  One process
+        # that searches every node's template is ROADMAP R2.
+        print(
+            f"p1 net: --backend {args.backend} with --nodes {args.nodes} "
+            "would start one process per node on one chip, and a chip "
+            "serves one process at a time (ROADMAP R2); use a host "
+            "backend (cpu, native, numpy) or --nodes 1",
+            file=sys.stderr,
+        )
+        return 2
 
     # Validate the retarget flag pair up front: a bad pair must be ONE
     # clean CLI error here, not N child-node tracebacks (or — for a lone

@@ -1010,6 +1010,9 @@ class Node:
         #: Set when a store failure should end the process instead of
         #: degrading (``--store-degraded-exit``); the CLI watches it.
         self.store_fatal = asyncio.Event()
+        #: Set when the mining loop died of an exception (a device search
+        #: that raised, say); the CLI exits non-zero on it.
+        self.mine_failed = asyncio.Event()
         #: Overload resilience (node/governor.py): per-peer admission
         #: budgets, the write-queue caps, and the SHED state machine over
         #: the accounted memory gauge (``_memory_gauge``) — the third leg
@@ -4956,9 +4959,11 @@ class Node:
             raise
         except Exception:
             # A silently dead miner looks like a healthy idle node; make
-            # the failure loud here — stop_mining() swallows (logs) the
-            # re-raise so teardown still completes.
+            # the failure loud here and flag it for the process exit code
+            # — stop_mining() swallows (logs) the re-raise so teardown
+            # still completes.
             self.log.exception("mining loop died")
+            self.mine_failed.set()
             raise
 
     async def _mine_loop_inner(self) -> None:

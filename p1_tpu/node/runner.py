@@ -68,8 +68,11 @@ async def run_node(args, miner=None) -> int:
     await node.start()
     # --store-degraded-exit watch: the node signals instead of exiting
     # itself so teardown (final status line, mempool save, store close)
-    # still runs through the one path below.  Exit code 4.
+    # still runs through the one path below.  Exit code 4.  A mining loop
+    # that died ends the process the same way, with exit code 5.
     fatal = asyncio.ensure_future(node.store_fatal.wait())
+    mine_failed = asyncio.ensure_future(node.mine_failed.wait())
+    ends = {fatal, mine_failed}
     rc = 0
     try:
         if args.deadline is not None or args.duration is not None:
@@ -88,9 +91,13 @@ async def run_node(args, miner=None) -> int:
             # netharness workers sharing stderr) this line must say
             # WHICH node's window it is.
             node.log.info("mining window: %.2fs until deadline", window)
-            await asyncio.wait({fatal}, timeout=window)
+            await asyncio.wait(
+                ends, timeout=window, return_when=asyncio.FIRST_COMPLETED
+            )
             if fatal.done():
                 rc = 4
+            elif mine_failed.done():
+                rc = 5
             else:
                 # Quiesce: stop producing, then wait for the gossip
                 # backlog to drain (GIL-bound mining starves the event
@@ -115,15 +122,25 @@ async def run_node(args, miner=None) -> int:
                         break
         else:
             while True:
-                await asyncio.wait({fatal}, timeout=args.status_interval)
+                await asyncio.wait(
+                    ends,
+                    timeout=args.status_interval,
+                    return_when=asyncio.FIRST_COMPLETED,
+                )
                 if fatal.done():
                     rc = 4
+                    break
+                if mine_failed.done():
+                    rc = 5
                     break
                 print(json.dumps(node.status()), flush=True)
     except (KeyboardInterrupt, asyncio.CancelledError):
         pass
     finally:
         fatal.cancel()
+        mine_failed.cancel()
         print(json.dumps(node.status()), flush=True)
         await node.stop()
+    if rc == 0 and node.mine_failed.is_set():
+        rc = 5  # died during the quiesce, after the window's check
     return rc

@@ -123,4 +123,8 @@ def pod_leader_failover(args, deadline: float) -> None:
         argv += ["--platform", args.platform]
     logging.error("pod leader failing over to solo mining: %s", " ".join(argv))
     sys.stderr.flush()
+    # The new image must be the only owner of this host's chips: close
+    # every descriptor the TPU runtime may hold (device files, its lock
+    # file) whether or not it opened them close-on-exec.
+    os.closerange(3, os.sysconf("SC_OPEN_MAX"))
     os.execv(sys.executable, argv)

@@ -2,9 +2,9 @@
 
 The compiled Mosaic path needs real TPU hardware; these tests run the
 IDENTICAL kernel through the Pallas interpreter on the CPU test platform
-(pallas_backend auto-selects interpret mode off-TPU), so every lane of the
-round math, the target compare, and the first-hit min-reduction is checked
-without a chip.  Throughput of the compiled kernel is bench.py's job.
+(asked for with ``interpret=True``), so every lane of the round math, the
+target compare, and the first-hit min-reduction is checked without a chip.
+Throughput of the compiled kernel is bench.py's job.
 """
 
 import random
@@ -29,14 +29,21 @@ def _prefix(seed: int) -> bytes:
 
 @pytest.fixture(scope="module")
 def tpu_backend():
-    be = get_backend("tpu", batch=BATCH, sub=8)
-    assert be.interpret, "off-TPU the backend must auto-select interpret mode"
+    be = get_backend("tpu", batch=BATCH, sub=8, interpret=True)
+    assert be.interpret
     return be
 
 
 class TestPallasParity:
     def test_registered_as_tpu(self, tpu_backend):
         assert tpu_backend.name == "tpu"
+
+    @pytest.mark.parametrize("kwargs", [{}, {"batch": BATCH, "sub": 8}])
+    def test_refuses_without_tpu_unless_interpret(self, kwargs):
+        # No silent interpreter: off-TPU, only an explicit interpret=True
+        # runs the kernel; the default raises and names what JAX found.
+        with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
+            get_backend("tpu", **kwargs)
 
     @pytest.mark.parametrize("seed", [0, 7])
     def test_first_hit_matches_cpu(self, tpu_backend, seed):
@@ -77,17 +84,17 @@ class TestPallasParity:
 
     def test_batch_must_tile(self):
         with pytest.raises(ValueError, match="multiple"):
-            get_backend("tpu", batch=1000, sub=8)
+            get_backend("tpu", batch=1000, sub=8, interpret=True)
 
     def test_batch_int32_bound(self):
         with pytest.raises(ValueError, match="2\\*\\*31"):
-            get_backend("tpu", batch=1 << 31, sub=8)
+            get_backend("tpu", batch=1 << 31, sub=8, interpret=True)
 
     def test_odd_tile_disables_ramp(self):
         # sub=20 -> block 2560 doesn't divide the 2^22 ramp floor; the
         # backend must opt out of the opening ramp rather than crash on a
         # fresh low-difficulty search.
-        be = get_backend("tpu", batch=2560 * 4, sub=20)
+        be = get_backend("tpu", batch=2560 * 4, sub=20, interpret=True)
         assert be.ramp_floor is None
         res = be.search(_prefix(4), 0, 2560, 0)
         assert res.nonce == 0

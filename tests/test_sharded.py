@@ -146,7 +146,7 @@ class TestPallasInMesh:
         # a 2-device span, exercising the pcast + pmin plumbing around the
         # pallas_call.
         backend = get_backend(
-            "sharded", batch=2048, n_devices=2, kernel="pallas"
+            "sharded", batch=2048, n_devices=2, kernel="pallas", interpret=True
         )
         assert backend.kernel == "pallas"
         prefix = _prefix(35)
@@ -161,7 +161,7 @@ class TestPallasInMesh:
         # first-hit parity with the host scan — not extrapolated from
         # the 2-device case above.
         backend = get_backend(
-            "sharded", batch=2048, n_devices=8, kernel="pallas"
+            "sharded", batch=2048, n_devices=8, kernel="pallas", interpret=True
         )
         assert backend.kernel == "pallas"
         assert backend.n_devices == 8
@@ -184,6 +184,11 @@ class TestPallasInMesh:
     def test_cpu_mesh_defaults_to_xla_kernel(self):
         backend = get_backend("sharded", batch=256, n_devices=2)
         assert backend.kernel == "xla"
+
+    def test_pallas_kernel_on_cpu_mesh_needs_interpret(self):
+        backend = get_backend("sharded", batch=2048, n_devices=2, kernel="pallas")
+        with pytest.raises(RuntimeError, match="needs a TPU mesh.*'cpu'"):
+            backend.search(_prefix(35), 0, 4096, 8)
 
     def test_pallas_kernel_constructor_guards(self):
         with pytest.raises(ValueError, match="multiple"):

@@ -1,0 +1,84 @@
+"""The main path's device programs compile for a v5e chip, at real size.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (on-chip-measurement guide §2.3).  A compile that
+passes is not a chip run: nothing here runs, so it says nothing about
+results or times.  What it does catch, at no chip time, is what the chip's
+compiler would refuse — a tile not aligned to the layout, too much fast
+memory, a kernel that cannot be partitioned.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports every test file.  The persistent compile cache is off around these
+compiles (an entry written for a described chip cannot be read back).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+U32 = jnp.uint32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(sharding, *shapes):
+    return [jax.ShapeDtypeStruct(s, U32, sharding=sharding) for s in shapes]
+
+
+SEARCH_ARGS = ((8,), (3,), (8,), ())  # midstate, tail, target, nonce base
+
+
+def test_pallas_search_step_one_chip(topo):
+    from p1_tpu.hashx.pallas_backend import pallas_search_fn
+
+    step = pallas_search_fn(1 << 27, sub=16)
+    args = _shapes(SingleDeviceSharding(topo.devices[0]), *SEARCH_ARGS)
+    compiled = jax.jit(step).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_pallas_step_four_chips(topo):
+    from jax.sharding import Mesh
+    import numpy as np
+
+    from p1_tpu.hashx.sharded import AXIS, jit_sharded_step
+
+    mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
+    step = jit_sharded_step(mesh, 1 << 27, kernel="pallas")
+    args = _shapes(NamedSharding(mesh, P()), *SEARCH_ARGS)
+    text = step.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text  # the cross-chip pmin
+
+
+def test_replay_scan_config3(topo):
+    from p1_tpu.hashx.jax_sha256 import default_unroll, verify_header_chain_segments
+
+    fn = functools.partial(verify_header_chain_segments, unroll=default_unroll("tpu"))
+    args = _shapes(SingleDeviceSharding(topo.devices[0]), (2, 8192, 20), (8,), ())
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
