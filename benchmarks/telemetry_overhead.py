@@ -9,6 +9,10 @@ front door (decode, governor admission, add_block, store append, relay
 encode — everything a gossip frame pays) with telemetry enabled and
 disabled, best-of-N each, on one JSON line.
 
+``--spans`` times instead what the mining path's spans cost, with no
+profiler trace recording and with one: a registry span, a twin alone, a
+host-loop annotation, per call and per solo-d20 block (``span_cost``).
+
 It also emits the per-stage latency table (p50/p95/p99 from the enabled
 run's histograms) — the figure docs/PERF.md's "Telemetry plane" section
 records from a 10k-block run, and the ROADMAP-2 pipeline split will be
@@ -219,13 +223,96 @@ def bench_quick(blocks: int = 300, txs: int = 2, repeats: int = 3) -> dict:
     }
 
 
+#: What one solo-d20 block writes on the mining path (PERF.md, spans
+#: table): four registry spans (mine.assemble/search/post_seal,
+#: push.notify), three stage twins (validate, store, relay), the host
+#: loop's annotations (one prepare, 2.02 dispatches and 1.02 readbacks a
+#: block at d20: the cell's launches_per_block on a v5e), eight
+#: perf_counter reads around them, and the fold of the step counters.
+D20_SPANS, D20_TWINS, D20_ANNOTATIONS, D20_CLOCK_READS = 4, 3, 4.04, 8
+
+
+def span_cost(n: int = 200_000) -> dict:
+    """Per-call cost, in microseconds, of the mining path's spans with no
+    profiler trace recording (``off``) and with one recording (``on``),
+    and their sum for one solo-d20 block, all of it new: the mine loop
+    had no span before.  A registry span, a twin alone, a host-loop
+    annotation, a clock read, and the node's fold of one search's
+    counters into its registry."""
+    import tempfile
+
+    import jax  # the node's process has JAX loaded: twins are live
+
+    from p1_tpu.config import NodeConfig
+    from p1_tpu.miner import MineStats
+    from p1_tpu.node.node import Node
+
+    node = Node(NodeConfig(mine=False))
+    reg = node.telemetry
+    annotate = jax.profiler.TraceAnnotation
+    stats = MineStats(launches=2, nonces_launched=9 << 22, launches_abandoned=1,
+                      nonces_abandoned=1 << 25)
+
+    def per_call_us(body, calls: int) -> float:
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for i in range(calls):
+                body(i)
+            best = min(best, (time.perf_counter() - t0) / calls * 1e6)
+        return best
+
+    def span(i):
+        with reg.span("mine.search_s", height=i):
+            pass
+
+    def twin(i):
+        with reg.twin("stage.store_s", height=i):
+            pass
+
+    def annotation(i):
+        with annotate("p1.search.dispatch", launch=i, nonces=1 << 25):
+            pass
+
+    def measure(calls: int) -> dict:
+        out = {
+            "span_us": per_call_us(span, calls),
+            "twin_us": per_call_us(twin, calls),
+            "annotation_us": per_call_us(annotation, calls),
+            "clock_read_us": per_call_us(lambda i: time.perf_counter(), calls),
+            "fold_us": per_call_us(lambda i: node._record_steps(stats), calls),
+        }
+        out["d20_block_us"] = (
+            D20_SPANS * out["span_us"] + D20_TWINS * out["twin_us"]
+            + D20_ANNOTATIONS * out["annotation_us"]
+            + D20_CLOCK_READS * out["clock_read_us"] + out["fold_us"])
+        return {k: round(v, 4) for k, v in out.items()}
+
+    off = measure(n)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 1
+    options.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp, profiler_options=options)
+        try:
+            on = measure(n // 10)  # the trace keeps every event in memory
+        finally:
+            jax.profiler.stop_trace()
+    return {"off": off, "on": on}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--blocks", type=int, default=10_000)
     ap.add_argument("--txs", type=int, default=2, help="transfers per block")
     ap.add_argument("--repeats", type=int, default=3)
+    ap.add_argument("--spans", action="store_true",
+                    help="only the mining path's span cost (span_cost)")
     args = ap.parse_args(argv)
 
+    if args.spans:
+        print(json.dumps({"metric": "mining_span_cost_us", **span_cost()}))
+        return 0
     out = bench_quick(args.blocks, args.txs, args.repeats)
     from p1_tpu.hashx.perf_record import RECORDED_HOST_INGEST_BPS
 

@@ -20,10 +20,38 @@ The two operations every backend provides:
 from __future__ import annotations
 
 import abc
+import contextlib
 import dataclasses
+import threading
 from typing import Callable, Iterator
 
 from p1_tpu.core.header import NONCE_OFFSET
+
+
+@dataclasses.dataclass
+class StepCounts:
+    """Device-step counters of one or more ``search`` calls.
+
+    Filled by the device-stepped backends (``PipelinedSearchMixin``); the
+    host backends (cpu, native, numpy) have no steps and report none.  A
+    launch is *abandoned* when it is dispatched and never read back: the
+    step still in flight when ``search`` returns a hit.
+    """
+
+    launches: int = 0
+    nonces_launched: int = 0
+    launches_abandoned: int = 0
+    nonces_abandoned: int = 0
+    prepare_s: float = 0.0  # midstate and upload of the input arrays
+    dispatch_s: float = 0.0  # step launches, summed
+    readback_s: float = 0.0  # blocking reads of step results, summed
+
+    def add(self, other: "StepCounts") -> None:
+        for name in _STEP_FIELDS:
+            setattr(self, name, getattr(self, name) + getattr(other, name))
+
+
+_STEP_FIELDS = tuple(f.name for f in dataclasses.fields(StepCounts))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +60,32 @@ class SearchResult:
 
     nonce: int | None  # earliest hit, or None
     hashes_done: int  # how many candidates were evaluated
+    steps: StepCounts | None = None  # device-stepped backends only
+
+
+class _StepSpans(threading.local):
+    on = True
+
+
+_STEP_SPANS = _StepSpans()
+
+
+@contextlib.contextmanager
+def step_spans(on: bool):
+    """Whether the device-stepped backends write their ``p1.search.*``
+    profiler spans for searches made on this thread inside the block (on
+    by default).  A node with telemetry disabled turns them off, so that
+    its trace holds no span of the program."""
+    was = _STEP_SPANS.on
+    _STEP_SPANS.on = on
+    try:
+        yield
+    finally:
+        _STEP_SPANS.on = was
+
+
+def step_spans_on() -> bool:
+    return _STEP_SPANS.on
 
 
 class HashBackend(abc.ABC):

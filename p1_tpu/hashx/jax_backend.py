@@ -13,14 +13,25 @@ result until the next step is enqueued).
 
 from __future__ import annotations
 
-from typing import Callable
+import contextlib
+import functools
+import itertools
+import time
+from typing import Callable, Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from p1_tpu.core.header import target_from_difficulty, target_to_words
-from p1_tpu.hashx.backend import HashBackend, SearchResult, register
+from p1_tpu.hashx.backend import (
+    HashBackend,
+    SearchResult,
+    StepCounts,
+    register,
+    step_spans_on,
+)
 from p1_tpu.hashx.jax_sha256 import jit_search_step
 from p1_tpu.hashx.sha256_ref import header_midstate, header_tail_words, sha256d
 
@@ -61,6 +72,26 @@ _RAMP_FACTOR = 8
 _RAMP_MAX_DIFFICULTY = 26
 
 
+class _Launch(NamedTuple):
+    """One dispatched device step."""
+
+    id: int
+    base: int  # first nonce
+    valid: int  # nonces of the step inside the requested range
+    span: int  # nonces the device evaluates
+    idx: jax.Array  # the step's result, on the device
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str, **metadata):
+    """``TraceAnnotation``'s stand-in while step spans are off.  (With no
+    profiler trace recording, ``TraceAnnotation`` itself is a native
+    no-op, cheaper than this.)"""
+    return _NO_SPAN
+
+
 class PipelinedSearchMixin:
     """The host loop shared by every device-stepped backend.
 
@@ -76,6 +107,13 @@ class PipelinedSearchMixin:
     at difficulty 20 a 2²⁷-batch backend would otherwise spend ~10× the
     expected search time on granularity alone.  Throughput scans
     (high difficulty, or resumed ranges) skip the ramp entirely.
+
+    **Spans and counters**: each call returns its ``StepCounts`` with the
+    result, and writes profiler spans on the executor thread, on the
+    device ops' clock: ``p1.search.prepare`` (midstate and upload),
+    ``p1.search.dispatch`` (one per launch: ``launch`` id, ``nonces``
+    span) and ``p1.search.readback`` (the blocking read, ``launch`` id).
+    A launch with no readback span was abandoned.
     """
 
     step_span: int
@@ -97,11 +135,21 @@ class PipelinedSearchMixin:
         )
         return midstate, tail, target
 
+    @functools.cached_property
+    def _launch_ids(self) -> Iterator[int]:
+        """This backend's launch ids: 0, 1, 2, ... in dispatch order."""
+        return itertools.count()
+
     def search(
         self, header_prefix: bytes, nonce_start: int, count: int, difficulty: int
     ) -> SearchResult:
         self._check_search_args(header_prefix, nonce_start, count, difficulty)
-        midstate, tail, target = self._search_arrays(header_prefix, difficulty)
+        span_of = TraceAnnotation if step_spans_on() else _no_span
+        steps = StepCounts()
+        t = time.perf_counter()
+        with span_of("p1.search.prepare"):
+            midstate, tail, target = self._search_arrays(header_prefix, difficulty)
+        steps.prepare_s = time.perf_counter() - t
 
         ramping = (
             self.ramp_floor is not None
@@ -114,31 +162,43 @@ class PipelinedSearchMixin:
         # Batched scan with a one-step pipeline.  Each step covers
         # [base, base+span); a partial final step is masked on the host
         # by re-checking the hit offset against the remaining count.
-        pending: list[tuple[int, int, object]] = []  # (base, valid, device idx)
+        pending: list[_Launch] = []
         done = 0
-        result: SearchResult | None = None
-        while done < count and result is None:
+        hit: int | None = None
+        while done < count and hit is None:
             base = nonce_start + done
             valid = min(span, count - done)
-            idx = self._make_step(span)(midstate, tail, target, _U32(base))
-            pending.append((base, valid, idx))
+            launch = next(self._launch_ids)
+            t = time.perf_counter()
+            with span_of("p1.search.dispatch", launch=launch, nonces=span):
+                idx = self._make_step(span)(midstate, tail, target, _U32(base))
+            steps.dispatch_s += time.perf_counter() - t
+            steps.launches += 1
+            steps.nonces_launched += span
+            pending.append(_Launch(launch, base, valid, span, idx))
             done += valid
             span = min(span * _RAMP_FACTOR, self.step_span)
             if len(pending) > 1:
-                result = self._drain_one(pending, nonce_start)
-        while result is None and pending:
-            result = self._drain_one(pending, nonce_start)
-        if result is not None:
-            return result
-        return SearchResult(None, count)
+                hit = self._drain_one(pending, steps, span_of)
+        while hit is None and pending:
+            hit = self._drain_one(pending, steps, span_of)
+        # What is still pending was dispatched and is never read back: the
+        # step in flight behind a hit.
+        for p in pending:
+            steps.launches_abandoned += 1
+            steps.nonces_abandoned += p.span
+        if hit is None:
+            return SearchResult(None, count, steps)
+        return SearchResult(hit, hit - nonce_start + 1, steps)
 
-    def _drain_one(self, pending: list, nonce_start: int) -> SearchResult | None:
-        base, valid, idx = pending.pop(0)
-        offset = int(np.asarray(idx))  # blocks until this step is done
-        if offset < valid:
-            nonce = base + offset
-            return SearchResult(nonce, nonce - nonce_start + 1)
-        return None
+    def _drain_one(self, pending: list, steps: StepCounts, span_of) -> int | None:
+        """Read back the oldest pending step: its hit's nonce, or None."""
+        p = pending.pop(0)
+        t = time.perf_counter()
+        with span_of("p1.search.readback", launch=p.id):
+            offset = int(np.asarray(p.idx))  # blocks until this step is done
+        steps.readback_s += time.perf_counter() - t
+        return p.base + offset if offset < p.valid else None
 
 
 @register("jax")

@@ -179,9 +179,11 @@ def pallas_search_fn(
         # the varying-manual-axes checker rejects.
         out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
         interpret=interpret,
+        # The kernel's name in the compiled program and the device trace.
+        name="sha256d_search",
     )
 
-    def step(midstate, tail, target, nonce_base):
+    def pallas_search_step(midstate, tail, target, nonce_base):
         return call(
             midstate,
             tail,
@@ -191,7 +193,7 @@ def pallas_search_fn(
             jnp.asarray(_IV_WORDS),
         )[0].astype(_U32)
 
-    return step
+    return pallas_search_step
 
 
 @functools.cache

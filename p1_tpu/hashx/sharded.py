@@ -119,7 +119,8 @@ def jit_sharded_step(
         out_specs=P(),
         check_vma=check_vma,
     )
-    def step(midstate, tail, target, nonce_base):
+    def sharded_search_step(midstate, tail, target, nonce_base):
+        # Named: the mesh program's module in the device trace.
         d = lax.axis_index(AXIS).astype(_U32)
         base = nonce_base + d * _U32(batch_per_device)
         if check_vma:
@@ -136,7 +137,7 @@ def jit_sharded_step(
         global_off = jnp.where(hit, d * _U32(batch_per_device) + off, _U32(span))
         return lax.pmin(global_off, AXIS)
 
-    return step
+    return sharded_search_step
 
 
 @register("sharded")

@@ -18,14 +18,16 @@ import threading
 import time
 
 from p1_tpu.core.header import BlockHeader
-from p1_tpu.hashx.backend import HashBackend, get_backend
+from p1_tpu.hashx.backend import HashBackend, StepCounts, get_backend
 
 NONCE_SPACE = 1 << 32
 
 
 @dataclasses.dataclass
-class MineStats:
-    """Counters from one ``search_nonce`` call (metrics surface)."""
+class MineStats(StepCounts):
+    """Counters from one ``search_nonce`` call (metrics surface): the
+    device-step counters of its backend calls (``StepCounts``; all 0 on a
+    backend with no device steps) and the call's own."""
 
     hashes_done: int = 0
     elapsed_s: float = 0.0
@@ -94,6 +96,8 @@ class Miner:
                         prefix, nonce, count, header.difficulty
                     )
                     stats.hashes_done += res.hashes_done
+                    if res.steps is not None:
+                        stats.add(res.steps)
                     if res.nonce is not None:
                         return header.with_nonce(res.nonce)
                     nonce += count

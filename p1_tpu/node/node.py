@@ -40,6 +40,7 @@ from p1_tpu.core.block import Block, merkle_root
 from p1_tpu.core.header import BlockHeader
 from p1_tpu.core.sigcache import SignatureCache
 from p1_tpu.core.tx import Transaction
+from p1_tpu.hashx.backend import step_spans
 from p1_tpu.mempool import Mempool
 from p1_tpu.miner import Miner
 from p1_tpu.node import protocol
@@ -55,6 +56,7 @@ from p1_tpu.node.governor import (
 from p1_tpu.node.pipeline import NodePipeline, WorkerCrash
 from p1_tpu.node.protocol import Hello, MsgType
 from p1_tpu.node.supervision import RequestSupervisor
+from p1_tpu.node.telemetry import no_twin
 from p1_tpu.node.transport import SOCKET_TRANSPORT, Transport
 
 log = logging.getLogger("p1_tpu.node")
@@ -484,6 +486,15 @@ _METRIC_COUNTERS = (
 )
 #: Float-valued point-in-time fields (mining timing).
 _METRIC_GAUGES = ("mine_elapsed_s", "last_block_time_s")
+#: MineStats' device-step counters and times, folded into the registry
+#: as ``search.<name>`` after every search (Node._record_steps).
+_STEP_COUNTERS = (
+    "launches",
+    "nonces_launched",
+    "launches_abandoned",
+    "nonces_abandoned",
+)
+_STEP_TIMES = ("prepare_s", "dispatch_s", "readback_s")
 
 
 class NodeMetrics:
@@ -796,6 +807,9 @@ class Node:
             self._h_query = self.telemetry.histogram("query.request_s")
         else:
             self._tel_clock = None
+        #: The ``search.*`` counters and histograms, made at the first
+        #: device-stepped search (``_record_steps``).
+        self._search_series = None
         #: Deterministic 1-in-8 sampler for the PER-FRAME micro stages
         #: (frame decode, admission): they run for every frame at
         #: microsecond durations, so full recording would tax the hot
@@ -4713,7 +4727,11 @@ class Node:
         origin: _Peer | None = None,
         gossip: bool = True,
         sent_ts: float | None = None,
+        height: int | None = None,
     ):
+        """``height`` is given by the mine loop alone, for a block it
+        sealed: the block stages then write their profiler twins
+        (``p1.stage.*``), tagged with it, beside their histograms."""
         if self._store_degraded and block.block_hash() not in self.chain:
             # Degraded serve-only mode: a block we cannot persist is a
             # block we must not acknowledge — accepting it would let the
@@ -4731,24 +4749,32 @@ class Node:
         # plane").  Only mempool-reconstructed compact blocks serialize
         # fresh, once, on first use (their full frame never arrived).
         clk = self._tel_clock
+        if height is None:
+            twin = no_twin
+            tag = {}
+        else:
+            twin = self.telemetry.twin
+            tag = {"height": height}
         t0 = clk() if clk is not None else 0.0
-        if block.block_hash() not in self.chain:
-            # VALIDATE stage: batch-verify the block's transfer
-            # signatures into the verify-once cache on the pipeline's
-            # validate lane BEFORE the connect — add_block's check_block
-            # then hits the cache, so the Ed25519 cost (the old stage
-            # table's dominant term) is paid off-loop when staging is
-            # on.  Cache-warmer only: outcomes are check_block's alone,
-            # and a hostile invalid-signature block just pays its
-            # (bounded, ban-scored) verify at connect time instead.
-            await self.pipeline.run_validate(
-                preverify_signatures,
-                block.txs,
-                self.chain.genesis.block_hash(),
-                self.sig_cache,
-                nbytes=len(block.serialize()),
-            )
-        res = self.chain.add_block(block)
+        with twin("stage.validate_s", **tag):
+            if block.block_hash() not in self.chain:
+                # VALIDATE stage: batch-verify the block's transfer
+                # signatures into the verify-once cache on the pipeline's
+                # validate lane BEFORE the connect — add_block's
+                # check_block then hits the cache, so the Ed25519 cost
+                # (the old stage table's dominant term) is paid off-loop
+                # when staging is on.  Cache-warmer only: outcomes are
+                # check_block's alone, and a hostile invalid-signature
+                # block just pays its (bounded, ban-scored) verify at
+                # connect time instead.
+                await self.pipeline.run_validate(
+                    preverify_signatures,
+                    block.txs,
+                    self.chain.genesis.block_hash(),
+                    self.sig_cache,
+                    nbytes=len(block.serialize()),
+                )
+            res = self.chain.add_block(block)
         if clk is not None:
             self._h_validate.observe(clk() - t0)
         if res.status is AddStatus.ACCEPTED:
@@ -4781,7 +4807,8 @@ class Node:
             # incl. cascaded orphans; a failing disk degrades, never
             # unwinds this handler (_store_append).
             t0 = clk() if clk is not None else 0.0
-            await self._store_append(res.connected)
+            with twin("stage.store_s", **tag):
+                await self._store_append(res.connected)
             if clk is not None:
                 self._h_store.observe(clk() - t0)
             for b in res.connected:
@@ -4791,7 +4818,8 @@ class Node:
                 self.chain.filter_index.add_block(b)
             # Push plane: notify live subscriptions of the connect (the
             # no-subscriber case is a cursor fast-forward, not a build).
-            await self.subscriptions.notify()
+            with self.telemetry.span("push.notify_s", **tag):
+                await self.subscriptions.notify()
             if res.tip_changed:
                 if not gossip:
                     # Batch-synced tip movement: queue the one-shot
@@ -4816,8 +4844,9 @@ class Node:
                 # round (awaits included — the figure is what a tip push
                 # costs this event loop end to end).
                 t0 = clk() if clk is not None else 0.0
-                payload, saved_per_peer = self._block_gossip_payload(block)
-                n = await self._gossip(payload, skip=origin)
+                with twin("stage.relay_s", **tag):
+                    payload, saved_per_peer = self._block_gossip_payload(block)
+                    n = await self._gossip(payload, skip=origin)
                 if clk is not None:
                     self._h_relay.observe(clk() - t0)
                 if saved_per_peer and n:
@@ -4970,6 +4999,7 @@ class Node:
         import threading
 
         loop = asyncio.get_running_loop()
+        tel = self.telemetry
         while self._running:
             if (
                 self._store_degraded
@@ -4985,41 +5015,80 @@ class Node:
                 # clears / the revalidation flips.
                 await asyncio.sleep(0.25)
                 continue
-            candidate = self._assemble()
+            # Mine-loop spans (telemetry.py: histogram + profiler twin),
+            # each tagged with the candidate's height, the identifier
+            # one block's spans share.
+            height = self.chain.height + 1
+            with tel.span("mine.assemble_s", height=height):
+                candidate = self._assemble()
             self._abort = threading.Event()
             t0 = self.clock.monotonic()
-            sealed = await loop.run_in_executor(
-                None, self.miner.search_nonce, candidate.header, self._abort
+            # The backend's p1.search.* profiler spans are on by default.
+            search = (
+                self.miner.search_nonce
+                if tel.enabled
+                else self._search_without_spans
             )
+            with tel.span("mine.search_s", height=height):
+                sealed = await loop.run_in_executor(
+                    None, search, candidate.header, self._abort
+                )
             stats = self.miner.last_stats
             self.metrics.hashes_done += stats.hashes_done
             self.metrics.mine_elapsed_s += stats.elapsed_s
+            if stats.launches and tel.enabled:
+                self._record_steps(stats)
             if sealed is None:
                 continue  # aborted: tip moved under us, reassemble
-            block = Block(sealed, candidate.txs)
-            self.metrics.blocks_mined += 1
-            self.metrics.last_block_time_s = self.clock.monotonic() - t0
-            self.log.info(
-                "mined height=%d nonce=%d txs=%d t=%.3fs hps=%.0f",
-                self.chain.height + 1,
-                sealed.nonce,
-                len(block.txs),
-                self.metrics.last_block_time_s,
-                stats.hashes_per_sec,
-            )
-            # Shield the post-seal handling.  add_block + gossip happen
-            # inside the _post_seal task, which cancellation of THIS loop
-            # cannot kill; the guarantee that the sealed block lands in the
-            # chain and reaches peers comes from stop_mining()/stop()
-            # awaiting _post_seal, NOT from any ordering within this loop.
-            # Without the shield, cancellation between add_block and the
-            # gossip send strands the miner one block ahead forever.
-            self._post_seal = asyncio.create_task(
-                self._handle_block(block, origin=None)
-            )
-            await asyncio.shield(self._post_seal)
+            with tel.span("mine.post_seal_s", height=height):
+                block = Block(sealed, candidate.txs)
+                self.metrics.blocks_mined += 1
+                self.metrics.last_block_time_s = self.clock.monotonic() - t0
+                self.log.info(
+                    "mined height=%d nonce=%d txs=%d t=%.3fs hps=%.0f",
+                    self.chain.height + 1,
+                    sealed.nonce,
+                    len(block.txs),
+                    self.metrics.last_block_time_s,
+                    stats.hashes_per_sec,
+                )
+                # Shield the post-seal handling.  add_block + gossip happen
+                # inside the _post_seal task, which cancellation of THIS
+                # loop cannot kill; the guarantee that the sealed block
+                # lands in the chain and reaches peers comes from
+                # stop_mining()/stop() awaiting _post_seal, NOT from any
+                # ordering within this loop.  Without the shield,
+                # cancellation between add_block and the gossip send
+                # strands the miner one block ahead forever.
+                self._post_seal = asyncio.create_task(
+                    self._handle_block(block, origin=None, height=height)
+                )
+                await asyncio.shield(self._post_seal)
             self._post_seal = None
             await asyncio.sleep(0)  # let gossip/tx handlers breathe
+
+    def _search_without_spans(self, header: BlockHeader, abort):
+        """``miner.search_nonce`` on the executor thread, for a node with
+        telemetry off: its trace holds no span of the program."""
+        with step_spans(False):
+            return self.miner.search_nonce(header, abort)
+
+    def _record_steps(self, stats) -> None:
+        """Fold one search's device-step counters (``MineStats``) into
+        the registry: ``p1 metrics`` shows them.  The series are made at
+        the first fold, so a node whose backend has no device steps
+        shows none of them."""
+        if self._search_series is None:
+            tel = self.telemetry
+            self._search_series = (
+                [tel.counter(f"search.{n}") for n in _STEP_COUNTERS],
+                [tel.histogram(f"search.{n}") for n in _STEP_TIMES],
+            )
+        counters, hists = self._search_series
+        for c, n in zip(counters, _STEP_COUNTERS):
+            c.value += getattr(stats, n)
+        for h, n in zip(hists, _STEP_TIMES):
+            h.observe(getattr(stats, n))
 
     # -- introspection ---------------------------------------------------
 

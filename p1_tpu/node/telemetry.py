@@ -25,6 +25,13 @@ Design rules, in priority order:
   microsecond to ~two virtual minutes) plus a small ring buffer of
   recent raw samples; a long-lived node's telemetry memory is a
   constant.
+- **One span, two records.**  Where JAX is loaded, every span of an
+  enabled registry also opens a ``jax.profiler.TraceAnnotation`` of the
+  same region, on the device ops' clock: ``p1.`` + the histogram's name
+  without ``_s`` (``stage.store_s`` -> ``p1.stage.store``), with the
+  span's metadata (``height=...``).  It records only while a profiler
+  trace does (a native no-op otherwise), and this module never imports
+  JAX itself: sim nodes and CPU-only tools never load it.
 
 Export surfaces: the ``GETMETRICS`` wire frame (protocol v12,
 governor-admitted, SHED-droppable, served by `p1 serve` replicas too),
@@ -36,8 +43,10 @@ exposition), and per-scenario telemetry sections in sim/chaos reports
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import math
+import sys
 import time
 from array import array as _array
 from bisect import bisect_left as _bisect_left
@@ -52,6 +61,8 @@ __all__ = [
     "format_prometheus",
     "format_table",
     "merge_histograms",
+    "no_twin",
+    "profiler_name",
     "propagation_summary_ms",
 ]
 
@@ -269,21 +280,34 @@ def propagation_summary_ms(
     }
 
 
+@functools.cache
+def profiler_name(name: str) -> str:
+    """The profiler twin's name of a histogram: ``p1.`` + the name
+    without its ``_s`` seconds suffix."""
+    return "p1." + (name[:-2] if name.endswith("_s") else name)
+
+
 class _Span:
-    """One timed region: enter reads the clock, exit records the delta."""
+    """One timed region: enter reads the clock, exit records the delta;
+    its profiler twin, where there is one, opens and closes with it."""
 
-    __slots__ = ("_hist", "_clock", "_t0")
+    __slots__ = ("_hist", "_clock", "_t0", "_twin")
 
-    def __init__(self, hist: Histogram, clock):
+    def __init__(self, hist: Histogram, clock, twin):
         self._hist = hist
         self._clock = clock
+        self._twin = twin
 
     def __enter__(self):
+        if self._twin is not None:
+            self._twin.__enter__()
         self._t0 = self._clock()
         return self
 
     def __exit__(self, *exc):
         self._hist.observe(self._clock() - self._t0)
+        if self._twin is not None:
+            self._twin.__exit__(*exc)
         return False
 
 
@@ -300,6 +324,11 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+def no_twin(name: str, **metadata):
+    """``MetricsRegistry.twin``'s stand-in where no twin is wanted."""
+    return _NULL_SPAN
 
 
 class MetricsRegistry:
@@ -355,18 +384,36 @@ class MetricsRegistry:
         if self.enabled:
             self.histogram(name).observe(value)
 
-    def span(self, name: str):
+    def span(self, name: str, **metadata):
         """``with registry.span("stage.validate_s"): ...`` — times the
-        region into the named histogram; a no-op (zero clock reads)
-        when the registry is disabled.  Hot path: one dict get + one
-        small allocation per call (a fresh _Span per region keeps
+        region into the named histogram, and writes its profiler twin
+        (``metadata`` goes to the twin only); a no-op (zero clock
+        reads) when the registry is disabled.  Hot path: one dict get +
+        one small allocation per call (a fresh _Span per region keeps
         overlapping regions safe — relay spans hold across awaits)."""
         if not self.enabled:
             return _NULL_SPAN
         h = self.histograms.get(name)
         if h is None:
             h = self.histogram(name)
-        return _Span(h, self._clock)
+        # jax.profiler where JAX is loaded (the lookup imports nothing);
+        # the twin is made only while a trace records.
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        twin = None
+        if profiler is not None and profiler.TraceAnnotation.is_enabled():
+            twin = profiler.TraceAnnotation(profiler_name(name), **metadata)
+        return _Span(h, self._clock, twin)
+
+    def twin(self, name: str, **metadata):
+        """The profiler twin of histogram ``name`` alone, for a region
+        whose histogram is recorded by hand (the node's block stages);
+        a no-op when the registry is disabled or JAX is not loaded."""
+        if not self.enabled:
+            return _NULL_SPAN
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return _NULL_SPAN
+        return profiler.TraceAnnotation(profiler_name(name), **metadata)
 
     # -- export -----------------------------------------------------------
 

@@ -59,6 +59,9 @@ def test_pallas_search_step_one_chip(topo):
     args = _shapes(SingleDeviceSharding(topo.devices[0]), *SEARCH_ARGS)
     compiled = jax.jit(step).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # The names the device trace shows: the module and the kernel.
+    assert compiled.as_text().startswith("HloModule jit_pallas_search_step")
+    assert "%sha256d_search" in compiled.as_text()
 
 
 def test_sharded_pallas_step_four_chips(topo):
@@ -73,6 +76,8 @@ def test_sharded_pallas_step_four_chips(topo):
     text = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text  # the cross-chip pmin
+    assert text.startswith("HloModule jit_sharded_search_step")
+    assert "%sha256d_search" in text
 
 
 def test_replay_scan_config3(topo):
