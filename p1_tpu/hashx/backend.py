@@ -35,13 +35,17 @@ class StepCounts:
     Filled by the device-stepped backends (``PipelinedSearchMixin``); the
     host backends (cpu, native, numpy) have no steps and report none.  A
     launch is *abandoned* when it is dispatched and never read back: the
-    step still in flight when ``search`` returns a hit.
+    step still in flight when ``search`` returns a hit.  It is *skipped*
+    when it was chained to the launch that hit, and so did no hashing on
+    the device.
     """
 
     launches: int = 0
     nonces_launched: int = 0
     launches_abandoned: int = 0
     nonces_abandoned: int = 0
+    launches_skipped: int = 0
+    nonces_skipped: int = 0
     prepare_s: float = 0.0  # midstate and upload of the input arrays
     dispatch_s: float = 0.0  # step launches, summed
     readback_s: float = 0.0  # blocking reads of step results, summed

@@ -6,7 +6,7 @@ Capability parity: the ``JaxTPUBackend`` registry entry of the north star
 ``sharded`` backend (sharded.py), both of which reuse this module's
 pipelined host loop.  ``search`` runs a host loop of jitted device steps
 with **async double-buffering**: step k+1 is dispatched before step k's
-4-byte result is read back, so the device never idles on the host (JAX's
+8-byte result is read back, so the device never idles on the host (JAX's
 async dispatch gives this for free as long as we delay ``int()``-ing a
 result until the next step is enqueued).
 """
@@ -39,6 +39,10 @@ _U32 = jnp.uint32
 
 #: A step function: (midstate, tail, target, nonce_base) -> uint32 offset of
 #: the earliest hit in [nonce_base, nonce_base + step_span), or step_span.
+#: Chained, (midstate, tail, target, nonce_base, prior) -> result: the
+#: step's uint32 (offset, found) in one device array, ``found`` 1 when the
+#: step hit or skipped; ``prior`` is the result of the launch before, and a
+#: step whose prior's ``found`` is set skips its work.
 StepFn = Callable[..., jax.Array]
 
 #: Default device-step batch by platform.  The TPU sweep peaked near 2**24
@@ -79,7 +83,16 @@ class _Launch(NamedTuple):
     base: int  # first nonce
     valid: int  # nonces of the step inside the requested range
     span: int  # nonces the device evaluates
-    idx: jax.Array  # the step's result, on the device
+    result: jax.Array  # the step's (offset, found), on the device
+
+    def prior(self) -> jax.Array:
+        """The result the next launch is chained to.  Its flag is set
+        only when this launch hit below ``span``, which the host reads as
+        a hit only when the launch is full: a partial launch's hit beyond
+        ``valid`` is a miss.  A partial launch is the call's last, so
+        nothing is chained to one."""
+        assert self.valid == self.span, "a launch chained to a partial launch"
+        return self.result
 
 
 _NO_SPAN = contextlib.nullcontext()
@@ -108,6 +121,13 @@ class PipelinedSearchMixin:
     expected search time on granularity alone.  Throughput scans
     (high difficulty, or resumed ranges) skip the ramp entirely.
 
+    **Chained steps**: each launch takes the result of the launch before
+    it as an operand and skips its work on the device when that launch
+    hit, so the step in flight behind a hit costs little of the device's
+    time.  Offset and found flag share one array, so a launch adds one
+    buffer to the unchained step's, not two: the flag never leaves the
+    device, and the host neither waits for it nor uploads one per launch.
+
     **Spans and counters**: each call returns its ``StepCounts`` with the
     result, and writes profiler spans on the executor thread, on the
     device ops' clock: ``p1.search.prepare`` (midstate and upload),
@@ -120,9 +140,23 @@ class PipelinedSearchMixin:
     #: Smallest opening step; None disables the ramp (sharded backend: the
     #: per-device batch is baked into the mesh program).
     ramp_floor: int | None = _RAMP_FLOOR
+    #: The JAX platform the steps run on; None for the default backend.
+    platform: str | None = None
 
     def _make_step(self, span: int) -> StepFn:
+        """A jitted step of ``span`` nonces, which takes the chained form."""
         raise NotImplementedError
+
+    def _placement(self):
+        """Where the steps run: a device or sharding for ``device_put``,
+        None for the default device."""
+        return jax.devices(self.platform)[0] if self.platform else None
+
+    @functools.cached_property
+    def _no_prior(self) -> jax.Array:
+        """The prior result of a call's first launch, "no hit before it":
+        made once, and kept on the device."""
+        return jax.device_put(np.zeros(2, np.uint32), self._placement())
 
     def sha256d(self, data: bytes) -> bytes:
         return sha256d(data)  # single digests stay on host
@@ -163,6 +197,7 @@ class PipelinedSearchMixin:
         # [base, base+span); a partial final step is masked on the host
         # by re-checking the hit offset against the remaining count.
         pending: list[_Launch] = []
+        prior = self._no_prior
         done = 0
         hit: int | None = None
         while done < count and hit is None:
@@ -171,22 +206,29 @@ class PipelinedSearchMixin:
             launch = next(self._launch_ids)
             t = time.perf_counter()
             with span_of("p1.search.dispatch", launch=launch, nonces=span):
-                idx = self._make_step(span)(midstate, tail, target, _U32(base))
+                result = self._make_step(span)(
+                    midstate, tail, target, _U32(base), prior
+                )
             steps.dispatch_s += time.perf_counter() - t
             steps.launches += 1
             steps.nonces_launched += span
-            pending.append(_Launch(launch, base, valid, span, idx))
+            pending.append(_Launch(launch, base, valid, span, result))
             done += valid
+            if done < count:
+                prior = pending[-1].prior()
             span = min(span * _RAMP_FACTOR, self.step_span)
             if len(pending) > 1:
                 hit = self._drain_one(pending, steps, span_of)
         while hit is None and pending:
             hit = self._drain_one(pending, steps, span_of)
         # What is still pending was dispatched and is never read back: the
-        # step in flight behind a hit.
+        # step in flight behind a hit.  Each such launch is chained to the
+        # hit, or to a launch chained to it, and so skipped its work.
         for p in pending:
             steps.launches_abandoned += 1
             steps.nonces_abandoned += p.span
+            steps.launches_skipped += 1
+            steps.nonces_skipped += p.span
         if hit is None:
             return SearchResult(None, count, steps)
         return SearchResult(hit, hit - nonce_start + 1, steps)
@@ -196,7 +238,7 @@ class PipelinedSearchMixin:
         p = pending.pop(0)
         t = time.perf_counter()
         with span_of("p1.search.readback", launch=p.id):
-            offset = int(np.asarray(p.idx))  # blocks until this step is done
+            offset = int(np.asarray(p.result)[0])  # blocks until this step is done
         steps.readback_s += time.perf_counter() - t
         return p.base + offset if offset < p.valid else None
 

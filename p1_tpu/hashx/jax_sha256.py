@@ -298,19 +298,39 @@ def search_step(
     tail: jax.Array,
     target_words: jax.Array,
     nonce_base: jax.Array,
+    prior: jax.Array | None = None,
+    *,
     batch: int,
     unroll: int | None = None,
-) -> jax.Array:
+):
     """One device step: scan [nonce_base, nonce_base+batch) lanes, return
-    the first hit's offset from nonce_base, or ``batch`` if none."""
-    nonces = nonce_base + jnp.arange(batch, dtype=_U32)
-    hits = below_target(sha256d_words(midstate, tail, nonces, unroll), target_words)
-    return first_hit_index(hits, batch)
+    the first hit's offset from nonce_base, or ``batch`` if none.
+
+    Chained form: with ``prior``, the result of the step launched before,
+    it returns its own result, uint32 ``(offset, found)`` in one array, and
+    skips the scan, reading as a miss, when the prior's ``found`` is set;
+    ``found`` is 1 when this step hit or skipped."""
+
+    def scan(nonce_base):
+        nonces = nonce_base + jnp.arange(batch, dtype=_U32)
+        hits = below_target(
+            sha256d_words(midstate, tail, nonces, unroll), target_words
+        )
+        return first_hit_index(hits, batch)
+
+    if prior is None:
+        return scan(nonce_base)
+    skipped = prior[1] != 0
+    # The miss is built from ``nonce_base`` so that under shard_map both
+    # branches vary over the mesh alike.
+    offset = lax.cond(skipped, lambda b: b * _U32(0) + _U32(batch), scan, nonce_base)
+    return jnp.stack([offset, (skipped | (offset < _U32(batch))).astype(_U32)])
 
 
 @functools.cache
 def jit_search_step(batch: int, platform: str | None = None, unroll: int | None = None):
-    """Jitted ``search_step`` closed over a static batch size.
+    """Jitted ``search_step`` closed over a static batch size: the plain
+    four-operand step, or the chained one when called with ``prior``.
 
     ``unroll=None`` resolves per platform (see ``default_unroll``) before
     the trace is cut, so CPU tests get the second-scale compile and TPU

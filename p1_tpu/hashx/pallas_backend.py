@@ -22,10 +22,15 @@ Layout (SURVEY.md §7 step 3):
   into the round loop), so the Pallas/XLA/NumPy formulations stay
   lane-exact by construction.
 - Scalar plumbing in SMEM: midstate (8), chunk-2 tail words (3), target
-  (8), nonce base (1).  Output is a single SMEM uint32 — ``min`` over the
-  grid of the earliest hit's flat index (or ``batch``) — accumulated across
-  sequential grid steps, exactly the contract of jax_sha256.search_step, so
-  the pipelined host loop and the sharded pmin reduction compose unchanged.
+  (8), nonce base (1), prior result (2).  Output is a single SMEM uint32 —
+  ``min`` over the grid of the earliest hit's flat index (or ``batch``) —
+  accumulated across sequential grid steps, exactly the contract of
+  jax_sha256.search_step, so the pipelined host loop and the sharded pmin
+  reduction compose unchanged.
+- Chained steps: the prior result is the (offset, found) pair of the step
+  launched before this one.  When its found flag is set, that step's hit
+  ends the search, and this one skips every tile through the early exit
+  (see ``_search_kernel``).
 - ``interpret=True`` runs the identical kernel on CPU (tests; Mosaic needs
   real TPU hardware otherwise).
 """
@@ -70,6 +75,7 @@ def _search_kernel(
     tail_ref,
     target_ref,
     base_ref,
+    prior_ref,
     k_ref,
     iv_ref,
     out_ref,
@@ -91,12 +97,18 @@ def _search_kernel(
     step containing the hit used to grind out its remaining ~2²⁷ nonces
     (~0.12 s wasted per block at the north-star difficulty); now the
     remainder of the batch costs microseconds.
+
+    The same exit skips a whole launch: with the prior's found flag set
+    (``prior_ref[1]``), the output starts at ``batch + 1``, no grid step
+    passes the check, and the wrapper reads the sentinel as a miss.
     """
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        out_ref[0] = jnp.int32(batch)
+        out_ref[0] = jnp.where(
+            prior_ref[1] != 0, jnp.int32(batch + 1), jnp.int32(batch)
+        )
 
     @pl.when(out_ref[0] == jnp.int32(batch))  # no hit recorded yet
     def _():
@@ -141,6 +153,12 @@ def pallas_search_fn(
     """The UNJITTED Pallas search step: (midstate(8,), tail(3,), target(8,),
     nonce_base) -> uint32 first-hit offset in [0, batch] (``batch`` = miss).
 
+    Chained form: with a fifth operand ``prior``, the result of the step
+    launched before, it returns its own result, uint32 ``(offset, found)``
+    in one array, and skips its hashing when the prior's ``found`` is set.
+    ``found`` is 1 when this step hit or skipped, so every launch chained
+    behind a hit skips too.
+
     Composable into larger traced programs — the ``sharded`` backend calls
     it inside ``shard_map`` so each chip of a mesh runs the kernel on its
     own nonce block; ``jit_pallas_search_step`` is the single-device jitted
@@ -169,7 +187,7 @@ def pallas_search_fn(
     call = pl.pallas_call(
         kernel,
         grid=(batch // block,),
-        in_specs=[scalar_spec] * 6,
+        in_specs=[scalar_spec] * 7,
         out_specs=pl.BlockSpec(
             (1,), lambda i: (0,), memory_space=pltpu.SMEM
         ),
@@ -183,15 +201,20 @@ def pallas_search_fn(
         name="sha256d_search",
     )
 
-    def pallas_search_step(midstate, tail, target, nonce_base):
-        return call(
+    def pallas_search_step(midstate, tail, target, nonce_base, prior=None):
+        out = call(
             midstate,
             tail,
             target,
             jnp.reshape(nonce_base, (1,)),
+            jnp.zeros((2,), _U32) if prior is None else prior,
             jnp.asarray(_K_WORDS),
             jnp.asarray(_IV_WORDS),
-        )[0].astype(_U32)
+        )[0]
+        offset = jnp.minimum(out, batch).astype(_U32)  # a skip reads as a miss
+        if prior is None:
+            return offset
+        return jnp.stack([offset, (out != batch).astype(_U32)])
 
     return pallas_search_step
 
@@ -205,7 +228,7 @@ def jit_pallas_search_step(
     unroll: int | None = None,
 ) -> StepFn:
     """Jitted single-device ``pallas_search_fn`` (jit_search_step's exact
-    signature)."""
+    signature, the chained form included)."""
     step = pallas_search_fn(batch, sub, interpret, unroll)
     device = jax.devices(platform)[0] if platform else None
     return jax.jit(step, device=device)

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from p1_tpu.hashx.backend import HashBackend, register
 from p1_tpu.hashx.jax_backend import (
@@ -70,6 +70,12 @@ def jit_sharded_step(
     All inputs are replicated (``P()``); the output is replicated too —
     ``pmin`` makes it device-invariant, so any shard can be read back.
 
+    Chained form: called with a fifth, replicated operand ``prior`` (the
+    result of the mesh step launched before) it returns its own result,
+    ``(offset, found)`` in one array.  Each chip's body skips its hashing
+    when the prior's ``found`` is set; ``found`` is derived from the offset
+    after ``pmin``, so every chip holds the same result for the next step.
+
     ``kernel`` picks the per-device search body: ``"xla"`` (the jax_sha256
     formulation — also what CPU validation meshes run) or ``"pallas"``
     (the Mosaic kernel of pallas_backend inside shard_map, so every chip
@@ -111,31 +117,40 @@ def jit_sharded_step(
     # the explicit pcast promotion it requires.
     check_vma = kernel != "pallas"
 
-    @jax.jit
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
-        in_specs=(P(), P(), P(), P()),
+        in_specs=(P(), P(), P(), P(), P()),
         out_specs=P(),
         check_vma=check_vma,
     )
-    def sharded_search_step(midstate, tail, target, nonce_base):
-        # Named: the mesh program's module in the device trace.
+    def mesh_step(midstate, tail, target, nonce_base, prior):
         d = lax.axis_index(AXIS).astype(_U32)
         base = nonce_base + d * _U32(batch_per_device)
+        skip = prior
         if check_vma:
             # ``base`` varies per device, so the whole hash dataflow is
             # varying over the mesh axis; promote the replicated inputs to
             # match, or the fori_loop carry in the compression rejects the
             # mixed types.
-            midstate, tail, target = (
+            midstate, tail, target, skip = (
                 lax.pcast(x, AXIS, to="varying")
-                for x in (midstate, tail, target)
+                for x in (midstate, tail, target, prior)
             )
-        off = device_search(midstate, tail, target, base)
+        off = device_search(midstate, tail, target, base, skip)[0]
         hit = off < _U32(batch_per_device)
         global_off = jnp.where(hit, d * _U32(batch_per_device) + off, _U32(span))
-        return lax.pmin(global_off, AXIS)
+        global_off = lax.pmin(global_off, AXIS)
+        found = (prior[1] != 0) | (global_off < _U32(span))
+        return jnp.stack([global_off, found.astype(_U32)])
+
+    @jax.jit
+    def sharded_search_step(midstate, tail, target, nonce_base, prior=None):
+        # Named: the mesh program's module in the device trace.
+        if prior is None:
+            no_prior = jnp.zeros((2,), _U32)
+            return mesh_step(midstate, tail, target, nonce_base, no_prior)[0]
+        return mesh_step(midstate, tail, target, nonce_base, prior)
 
     return sharded_search_step
 
@@ -215,3 +230,6 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
         return jit_sharded_step(
             self.mesh, self.batch, self.unroll, self.kernel, self.interpret
         )
+
+    def _placement(self):
+        return NamedSharding(self.mesh, P())

@@ -493,6 +493,8 @@ _STEP_COUNTERS = (
     "nonces_launched",
     "launches_abandoned",
     "nonces_abandoned",
+    "launches_skipped",
+    "nonces_skipped",
 )
 _STEP_TIMES = ("prepare_s", "dispatch_s", "readback_s")
 

@@ -50,8 +50,8 @@ class _SpanSpyBackend(PipelinedSearchMixin, HashBackend):
     def _make_step(self, span):
         self.spans.append(span)
 
-        def step(midstate, tail, target, base):
-            return jnp.uint32(span)  # never a hit
+        def step(midstate, tail, target, base, prior):
+            return jnp.array([span, prior[1]], jnp.uint32)  # never a hit
 
         return step
 
@@ -87,8 +87,8 @@ class TestOpeningRamp:
                 self.spans.append(span)
                 off = self.hit_offset
 
-                def step(midstate, tail, target, base):
-                    return jnp.uint32(off if off < span else span)
+                def step(midstate, tail, target, base, prior):
+                    return jnp.array([min(off, span), off < span], jnp.uint32)
 
                 return step
 
@@ -114,6 +114,67 @@ class TestOpeningRamp:
 
         spans = self._scan(_RAMP_FLOOR // 2, _RAMP_FLOOR, difficulty=20)
         assert all(s == _RAMP_FLOOR // 2 for s in spans)
+
+
+class _Planted(_SpanSpyBackend):
+    """A device whose hits are planted nonces; it keeps the chained
+    contract (skip on a set prior) and records each launch as
+    ``(span, base, skipped)``."""
+
+    def __init__(self, step_span, hits):
+        super().__init__(step_span)
+        self.ramp_floor = 512
+        self.hits = sorted(hits)
+        self.launched = []
+
+    def _make_step(self, span):
+        def step(midstate, tail, target, base, prior):
+            skipped = bool(prior[1])
+            self.launched.append((span, int(base), skipped))
+            first = next((h - int(base) for h in self.hits
+                          if int(base) <= h < int(base) + span), span)
+            off = span if skipped else first
+            return jnp.array([off, skipped or off < span], jnp.uint32)
+
+        return step
+
+
+class TestChainedSteps:
+    """The host loop chains each launch to the one before: results equal
+    a plain scan of the range, and exactly the launches behind the hit
+    skip their work."""
+
+    @pytest.mark.parametrize("start,count,hits", [
+        (0, 1 << 15, [300, 1000]),  # the first ramp step (512)
+        (0, 1 << 15, [512 + 4096 + 17]),  # a middle step
+        (4096, 3 * 4096, [4096 + 2 * 4096 + 5]),  # the full last step
+        (4096, 2 * 4096 + 100, [4096 + 2 * 4096 + 200]),  # beyond a partial last step
+        (4096, 2 * 4096 + 100, [4096 + 2 * 4096 + 50]),  # inside it
+    ], ids=["first-ramp", "middle", "full-last", "partial-beyond", "partial-inside"])
+    def test_matches_a_plain_scan(self, start, count, hits):
+        be = _Planted(4096, hits)
+        res = be.search(_prefix(0), start, count, 20)
+        want = min((h for h in hits if start <= h < start + count), default=None)
+        assert res.nonce == want
+        assert res.hashes_done == (count if want is None else want - start + 1)
+        # A launch skips exactly when a launch before it in the call hit.
+        hit_at = [i for i, (span, base, _) in enumerate(be.launched)
+                  if any(base <= h < base + span for h in hits)]
+        skipped = [s for *_, s in be.launched]
+        first = hit_at[0] if hit_at else len(skipped)
+        assert skipped == [False] * (first + 1) + [True] * (len(skipped) - first - 1)
+        s = res.steps
+        assert (s.launches_skipped, s.nonces_skipped) == (
+            s.launches_abandoned, s.nonces_abandoned)
+        assert s.launches_skipped == sum(skipped)
+
+    def test_no_launch_is_chained_to_a_partial_one(self):
+        from p1_tpu.hashx.jax_backend import _Launch
+
+        result = jnp.array([5, 1], jnp.uint32)
+        assert _Launch(0, 0, 4096, 4096, result).prior() is result
+        with pytest.raises(AssertionError, match="partial"):
+            _Launch(0, 0, 100, 4096, jnp.array([200, 1], jnp.uint32)).prior()
 
 
 class TestJaxSha256:

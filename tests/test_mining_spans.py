@@ -121,6 +121,8 @@ class TestMineStatsCounters:
         s = miner.last_stats
         assert (s.launches, s.nonces_launched) == (2, 512 + 4096)
         assert (s.launches_abandoned, s.nonces_abandoned) == (1, 4096)
+        # Chained to the hit, the step in flight skipped its work.
+        assert (s.launches_skipped, s.nonces_skipped) == (1, 4096)
         assert s.prepare_s > 0 and s.dispatch_s > 0 and s.readback_s > 0
         assert s.hashes_done == sealed.nonce + 1
 
@@ -134,6 +136,7 @@ class TestMineStatsCounters:
         s = miner.last_stats
         assert (s.launches, s.nonces_launched) == (3, 3 * 4096)
         assert (s.launches_abandoned, s.nonces_abandoned) == (0, 0)
+        assert (s.launches_skipped, s.nonces_skipped) == (0, 0)
         assert s.hashes_done == 3 * 4096
 
     def test_search_result_carries_the_counts(self):
@@ -224,6 +227,8 @@ class TestNodeTrace:
         assert counters["search.launches_abandoned"] == len(set(launched) - read) > 0
         assert counters["search.nonces_abandoned"] == sum(
             n for i, n in launched.items() if i not in read)
+        assert counters["search.launches_skipped"] == counters["search.launches_abandoned"]
+        assert counters["search.nonces_skipped"] == counters["search.nonces_abandoned"]
         hists = node.telemetry_snapshot()["histograms"]
         for name in ("mine.assemble_s", "mine.search_s", "mine.post_seal_s", "push.notify_s",
                      "search.prepare_s", "search.dispatch_s", "search.readback_s"):

@@ -90,6 +90,35 @@ class TestPallasParity:
         with pytest.raises(ValueError, match="2\\*\\*31"):
             get_backend("tpu", batch=1 << 31, sub=8, interpret=True)
 
+    @pytest.mark.parametrize("prior", [None, 0, 1])
+    @pytest.mark.parametrize("difficulty", [DIFF, 255])
+    def test_chained_step(self, difficulty, prior):
+        # The prior flag set: the kernel skips every tile and the step
+        # reads as a miss, its flag set for the step after it.  Clear: the
+        # offset of the four-operand form, and a flag set only on a hit.
+        import jax.numpy as jnp
+
+        from p1_tpu.core import target_from_difficulty, target_to_words
+        from p1_tpu.hashx import sha256_ref
+        from p1_tpu.hashx.pallas_backend import jit_pallas_search_step
+
+        prefix = _prefix(5)
+        arrays = [jnp.array(words, dtype=jnp.uint32) for words in (
+            sha256_ref.header_midstate(prefix), sha256_ref.header_tail_words(prefix),
+            target_to_words(target_from_difficulty(difficulty)))]
+        step = jit_pallas_search_step(BATCH, 8, interpret=True)
+        truth = get_backend("cpu").search(prefix, 0, BATCH, difficulty).nonce
+        want = BATCH if truth is None else truth
+        if prior is None:
+            assert int(step(*arrays, jnp.uint32(0))) == want
+            return
+        result = step(*arrays, jnp.uint32(0), jnp.array([BATCH, prior], jnp.uint32))
+        offset, found = (int(x) for x in result)
+        if prior:
+            assert (offset, found) == (BATCH, 1)
+        else:
+            assert (offset, found) == (want, int(truth is not None))
+
     def test_odd_tile_disables_ramp(self):
         # sub=20 -> block 2560 doesn't divide the 2^22 ramp floor; the
         # backend must opt out of the opening ramp rather than crash on a

@@ -181,6 +181,40 @@ class TestPallasInMesh:
         assert got.nonce == truth.nonce
         assert got.hashes_done == truth.hashes_done
 
+    def test_chained_mesh_matches_one_device(self):
+        # Three mesh steps of 4 x 2048 with the first hit in the second,
+        # past chip 0's block: the third step is chained to it and skips.
+        # The four-chip mesh and one device of the same span agree on the
+        # hit and on every counter; the mesh's chained step itself reads a
+        # set prior as a miss whose flag stays set.
+        span, difficulty = 4 * 2048, 13
+        cpu = get_backend("cpu")
+        for seed in range(100, 300):
+            prefix = _prefix(seed)
+            truth = cpu.search(prefix, 0, 3 * span, difficulty)
+            if truth.nonce is not None and span + 2048 <= truth.nonce < 2 * span:
+                break
+        else:
+            pytest.fail("no seed with its first hit in the second step, past chip 0")
+        mesh = get_backend("sharded", batch=2048, n_devices=4, kernel="pallas",
+                           interpret=True)
+        one = get_backend("tpu", batch=span, interpret=True)
+        for backend in (mesh, one):
+            got = backend.search(prefix, 0, 3 * span, difficulty)
+            assert (got.nonce, got.hashes_done) == (truth.nonce, truth.hashes_done)
+            s = got.steps
+            assert (s.launches, s.launches_abandoned, s.launches_skipped) == (3, 1, 1)
+            assert s.nonces_skipped == span
+        step = sharded.jit_sharded_step(mesh.mesh, 2048, kernel="pallas", interpret=True)
+        arrays = _arrays(prefix, difficulty)
+        base = jnp.uint32(span)
+        no_prior, prior_hit = (jnp.array([span, f], jnp.uint32) for f in (0, 1))
+        offset, found = step(*arrays, base, no_prior)
+        assert (int(offset), int(found)) == (truth.nonce - span, 1)
+        assert int(step(*arrays, base)) == truth.nonce - span
+        offset, found = step(*arrays, base, prior_hit)
+        assert (int(offset), int(found)) == (span, 1)
+
     def test_cpu_mesh_defaults_to_xla_kernel(self):
         backend = get_backend("sharded", batch=256, n_devices=2)
         assert backend.kernel == "xla"

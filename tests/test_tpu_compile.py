@@ -80,6 +80,31 @@ def test_sharded_pallas_step_four_chips(topo):
     assert "%sha256d_search" in text
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_chained_search_step(topo, chips):
+    """The form the host loop launches: a fifth operand, the result of the
+    launch before, and one result out; still one kernel op a launch."""
+    from jax.sharding import Mesh
+    import numpy as np
+
+    from p1_tpu.hashx.pallas_backend import pallas_search_fn
+    from p1_tpu.hashx.sharded import AXIS, jit_sharded_step
+
+    if chips == 1:
+        step = jax.jit(pallas_search_fn(1 << 27, sub=16))
+        sharding = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
+        step = jit_sharded_step(mesh, 1 << 27, kernel="pallas")
+        sharding = NamedSharding(mesh, P())
+    args = _shapes(sharding, *SEARCH_ARGS)
+    prior = jax.ShapeDtypeStruct((2,), U32, sharding=sharding)
+    text = step.lower(*args, prior).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%sha256d_search" in text
+    assert ("all-reduce" in text) == (chips == 4)
+
+
 def test_replay_scan_config3(topo):
     from p1_tpu.hashx.jax_sha256 import default_unroll, verify_header_chain_segments
 
