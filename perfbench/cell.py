@@ -1,10 +1,12 @@
 """A cell, found by name: its entry in BENCHMARK.json, its configuration's
-file, its traffic's file and the readers of its metrics.
+file, its traffic's file, the driver that traffic names and the readers of
+its metrics.
 
-Nothing here knows a cell, a configuration, a traffic mix or a metric by
-name.  A later PR adds one by adding its file (``configs/<name>.json``,
-``traffic/<name>.json``, ``metrics/<name>.py``) and its entry in
-BENCHMARK.json; ``tests/test_cell.py`` shows that nothing else is needed.
+Nothing here knows a cell, a configuration, a traffic mix, a driver or a
+metric by name.  A later PR adds one by adding its file
+(``configs/<name>.json``, ``traffic/<name>.json``, ``drivers/<name>.py``,
+``metrics/<name>.py``) and its entry in BENCHMARK.json;
+``tests/test_cell.py`` shows that nothing else is needed.
 """
 
 from __future__ import annotations
@@ -13,6 +15,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import sys
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -22,6 +25,8 @@ ROOT = HERE.parent
 class Cell:
     name: str
     chips: int
+    root: pathlib.Path  # the checkout
+    driver: str  # the traffic's "driver": drivers/<driver>.py
     config: dict  # configs/<config>.json, as run
     traffic: dict  # traffic/<traffic>.json
     end_to_end: list[dict]  # BENCHMARK.json entries that apply to this cell
@@ -43,15 +48,36 @@ def load(workload: str, root: pathlib.Path = ROOT) -> Cell:
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
     here = root / "perfbench"
     traffic = json.loads((here / "traffic" / f"{w['traffic']}.json").read_text())
+    if "driver" not in traffic:
+        raise KeyError(f"traffic {w['traffic']!r} names no driver")
+    if not (here / "drivers" / f"{traffic['driver']}.py").is_file():
+        raise KeyError(f"no driver {traffic['driver']!r} (traffic {w['traffic']!r})")
     return Cell(
         name=workload,
         chips=w["chips"],
+        root=root,
+        driver=traffic["driver"],
         config=config,
         traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
         metrics_dir=here / "metrics",
     )
+
+
+def driver(cell: Cell):
+    """``drivers/<cell.driver>.py`` of the cell's checkout, as a module
+    (``drivers/__init__.py`` says what it provides)."""
+    path = cell.root / "perfbench" / "drivers" / f"{cell.driver}.py"
+    name = f"perfbench.drivers.{cell.driver}"
+    module = sys.modules.get(name)
+    if module is not None and pathlib.Path(module.__file__).resolve() == path.resolve():
+        return module
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module  # dataclasses look their module up by name
+    spec.loader.exec_module(module)
+    return module
 
 
 def reader(metrics_dir: pathlib.Path, name: str):

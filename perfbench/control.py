@@ -1,14 +1,12 @@
-"""The control: the timed path with the guarantee that the sealed nonce is
-the first hit broken, which the comparison must call not correct.
+"""The control: the timed path with one guarantee of the configuration
+broken, which the comparison must call not correct.
 
     python3 -m perfbench.control --workload <name> --seeds 1,2,3 --seconds <s>
 
-Each block is sealed with the header's second hit in nonce order: a
-valid proof of work, so the node accepts it and every other layer is as
-usual, but not the first hit.  This is the shortcut a faster miner
-would be tempted by (a pod that takes any chip's hit, a kernel that
-returns any lane's), and only ``not_first`` can see it.  The runs are
-those of ``perfbench.run`` in one process, one seed after another.  The
+The cell's driver supplies it (``control`` in ``drivers/<name>.py``):
+for ``mine`` every block sealed at its second hit, for ``replay`` a scan
+that skips the chain's last segment.  The runs are those of
+``perfbench.run`` in one process, one seed after another.  The
 benchmark's own runs never install it.
 """
 
@@ -17,21 +15,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from perfbench import cell as cells
 from perfbench import run
-
-
-def second_hit(node) -> None:
-    """Seal every block with the next hit after the first."""
-    miner = node.miner
-    search = miner.search_nonce
-
-    def search_nonce(header, abort=None, start_nonce=0):
-        first = search(header, abort, start_nonce)
-        if first is None:
-            return None
-        return search(header, abort, first.nonce + 1)
-
-    miner.search_nonce = search_nonce
 
 
 def main(argv=None) -> int:
@@ -41,10 +26,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", required=True)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
+    control = cells.driver(cells.load(args.workload)).control
     rc = 0
     for seed in args.seeds.split(","):
         argv1 = ["--workload", args.workload, "--seed", seed, "--seconds", args.seconds]
-        rc |= run.main(argv1 + (["--rehearse"] if args.rehearse else []), patch=second_hit)
+        rc |= run.main(argv1 + (["--rehearse"] if args.rehearse else []), patch=control)
     return rc
 
 

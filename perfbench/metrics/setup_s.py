@@ -1,6 +1,7 @@
-"""Process start to the seal that opens the window: JAX and device
-start-up, node start, the cell's device steps compiled or loaded, and
-the warm-up blocks."""
+"""Process start to the end of the call that opens the window: JAX and
+device start-up, the cell's own set-up (a node started, or a chain built
+or loaded), the cell's device programs compiled or loaded, and the
+warm-up."""
 
 
 def read(run):

@@ -1,10 +1,13 @@
-"""Operations per SHA-256d mining candidate, counted from FIPS 180-4.
+"""Operations per SHA-256d mining candidate, and per header of a chain
+verified, counted from FIPS 180-4.
 
-The numerator of the kernel roofline.  It counts the work the
+The numerators of the kernel and scan rooflines.  It counts the work the
 specification defines, whatever implements it: with the midstate
 precomputed, a candidate is one compression of the header's second
 chunk, one compression of the 32-byte digest, and a 256-bit compare with
-the target.  Every 32-bit add, and, xor, not, shift and rotate is one
+the target.  A header of a chain verified is three compressions (its two
+chunks, then its digest), the compare with the target, and the compare
+of its previous hash with its parent's digest.  Every 32-bit add, and, xor, not, shift and rotate is one
 operation (a rotate is one even where the hardware needs three).  No
 implementation trick is counted: not the constant words of the padding,
 not an early exit, not a skipped last round.  So a kernel that issues
@@ -26,8 +29,16 @@ COMPRESSION = SCHEDULE + ROUNDS + 8
 #: ``digest < target`` over 8 limbs: a compare per limb, and for the 7
 #: upper limbs an equality, an and and an or.
 COMPARE = 8 + 7 * 3
+#: The previous hash against the parent's digest: a compare per word and
+#: 7 ands.
+LINK = 8 + 7
 
 
 def sha256d_candidate_ops() -> int:
     """32-bit operations the spec needs to test one nonce."""
     return 2 * COMPRESSION + COMPARE
+
+
+def header_verify_ops() -> int:
+    """32-bit operations the spec needs to verify one header of a chain."""
+    return 3 * COMPRESSION + COMPARE + LINK

@@ -6,6 +6,10 @@ difficulty, nonce); its proof of work is SHA-256d of those bytes, read
 as a big-endian integer, below ``2**(256 - difficulty)``.
 
 - ``pow_ok``: every sealed header, with ``hashlib``.
+- ``chain_first_invalid``: the first header of a packed fixed-difficulty
+  chain that breaks a rule (genesis identity, the declared difficulty,
+  the link to its parent's SHA-256d, proof of work from header 1 on), by
+  a sequential ``hashlib`` loop over the bytes.
 - ``FirstHitScan``: whether a sealed nonce is the first hit in canonical
   order, i.e. no nonce in ``[0, nonce)`` of the same header meets the
   target.  At d28 that is ~2**28 digests a block, out of reach of
@@ -25,6 +29,11 @@ import struct
 from perfbench import sha256
 
 HEADER = struct.Struct(">I32s32sIII")
+#: Genesis of a fixed-difficulty chain: version 1, zero previous hash and
+#: merkle root, 2025-01-01T00:00:00Z, nonce 0 (the program's fixed genesis,
+#: restated here).
+GENESIS_VERSION = 1
+GENESIS_TIMESTAMP = 1735689600
 
 
 def header_bytes(version, prev_hash, merkle_root, timestamp, difficulty, nonce) -> bytes:
@@ -37,6 +46,40 @@ def sha256d(data: bytes) -> bytes:
 
 def pow_ok(raw: bytes, difficulty: int) -> bool:
     return int.from_bytes(sha256d(raw), "big") < (1 << (256 - difficulty))
+
+
+def genesis(difficulty: int) -> bytes:
+    return header_bytes(GENESIS_VERSION, bytes(32), bytes(32), GENESIS_TIMESTAMP, difficulty, 0)
+
+
+def chain_first_invalid(raw: bytes, difficulty: int, start: int = 0) -> int | None:
+    """The index of the first header of the packed chain ``raw`` (80 bytes
+    each) that breaks a rule, or None where every header keeps them.
+    Header 0 must be the genesis of a chain at ``difficulty``, byte for
+    byte; every later header declares ``difficulty``, names its parent's
+    SHA-256d as its previous hash and meets its target.  With ``start``,
+    the headers before it are taken as checked: a chain that differs from
+    a checked one only from ``start`` on is checked from there."""
+    size = HEADER.size
+    n = len(raw) // size
+    data = memoryview(raw)
+    if start == 0:
+        if n and bytes(data[:size]) != genesis(difficulty):
+            return 0
+        start = 1
+    if start >= n:
+        return None
+    target = 1 << (256 - difficulty)
+    want = difficulty.to_bytes(4, "big")
+    prev = sha256d(data[(start - 1) * size:start * size])
+    for i in range(start, n):
+        h = data[i * size:(i + 1) * size]
+        digest = sha256d(h)
+        if (h[4:36] != prev or h[72:76] != want
+                or int.from_bytes(digest, "big") >= target):
+            return i
+        prev = digest
+    return None
 
 
 @functools.cache

@@ -23,7 +23,6 @@ import time
 T_PROCESS = time.perf_counter()  # set-up is timed from here
 
 import argparse  # noqa: E402
-import asyncio  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -33,8 +32,6 @@ import tempfile  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 PEAKS = pathlib.Path(__file__).resolve().parent / "peaks.json"
-#: The device reference scan's step and round unroll, by platform.
-SCAN = {"tpu": (1 << 22, 64), "cpu": (1 << 12, 1)}
 
 
 def parse(argv):
@@ -53,19 +50,10 @@ def _refuse(msg: str) -> int:
     return 2
 
 
-def hashes_done_gap(win) -> int:
-    """The program's own count of the window's hashes less the harness's:
-    0 while both count nonces up to each hit."""
-    from perfbench import view
-
-    inside = win.seals[win.first + 1:win.last + 1] if win.blocks else []
-    return sum(s.hashes_done for s in inside if s.sealed is not None) - sum(
-        view.useful_hashes(s) for s in inside)
-
-
 def main(argv=None, patch=None, root: pathlib.Path = ROOT) -> int:
-    """``patch(node)``, for tests and the control only, breaks the timed
-    path underneath before the run starts."""
+    """``patch``, for tests and the control only, breaks the timed path
+    underneath before the run starts (what it is handed is the driver's:
+    ``drivers/__init__.py``)."""
     args = parse(argv)
     from perfbench import cell as cells
 
@@ -86,6 +74,7 @@ def main(argv=None, patch=None, root: pathlib.Path = ROOT) -> int:
         import p1_tpu  # noqa: F401
     except ImportError as exc:
         return _refuse(f"the program is not in this checkout: {exc}")
+    drv = cells.driver(cell)
     import jax
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -104,29 +93,24 @@ def main(argv=None, patch=None, root: pathlib.Path = ROOT) -> int:
         return _refuse(f"no peak for device kind {kind!r} in {PEAKS.name}")
     used = devices[:cell.chips]
 
-    from perfbench import checks, reference, trace, view
+    from perfbench import trace
     from perfbench.compile_meter import CompileMeter
-    from perfbench.drive import drive
 
     traffic = dict(cell.traffic, **(cell.traffic["rehearsal"] if args.rehearse else {}))
     meter = CompileMeter()
     trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-") if args.trace else None
     try:
-        win = asyncio.run(drive(
-            cell, traffic["chain"], args.seconds, difficulty=traffic["difficulty"],
-            warmup_blocks=traffic["warmup_blocks"], rehearse=args.rehearse,
-            t_process=T_PROCESS, trace_dir=trace_dir, patch=patch, meter=meter))
+        win = drv.drive(cell, traffic, args.seconds, rehearse=args.rehearse,
+                        t_process=T_PROCESS, trace_dir=trace_dir, patch=patch, meter=meter)
         stats = [d.memory_stats() or {} for d in used]
         memory_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
-        batch, unroll = SCAN[platform]
         t_ref = time.perf_counter()
-        scan = reference.FirstHitScan(used, batch, unroll)
-        found, attempted, failed = checks.compare(
-            win, args.seed, scan, traffic["first_hit_sample"])
+        found, attempted, failed = drv.compare(win, args.seed, used, traffic)
         reference_s = time.perf_counter() - t_ref
         reduced = None
         if trace_dir is not None and win.first is not None and win.last is not None:
-            reduced = trace.reduce(trace.load(trace_dir), win.first, win.last)
+            reduced = trace.reduce(trace.load(trace_dir), drv.SPAN, win.first, win.last,
+                                   drv.IDLE)
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -139,27 +123,20 @@ def main(argv=None, patch=None, root: pathlib.Path = ROOT) -> int:
     if args.rehearse:
         result["rehearsal"] = True
     else:
-        run = view.View.of(win, cell, reduced, peaks[kind])
+        run = drv.view(win, cell, reduced, peaks[kind])
         entries = cell.per_layer if args.trace else cell.end_to_end
         result["metrics"] = cells.read_metrics(cell, entries, run)
         if args.trace and reduced is not None:
             device["busy_s"] = sum(d.busy_ns for d in reduced.devices) / len(reduced.devices) / 1e9
             device["window_s"] = reduced.window_ns / 1e9
             result["breakdown"] = reduced.breakdown()
-    blocks = win.blocks
-    win.phases = {"devices": t_devices, **win.phases}
     result["facts"] = {
-        "blocks": len(blocks),
-        "first_nonces": [h.nonce for h in win.served[1:6]],
-        "height": len(win.served) - 1,
-        "batch": win.batch,
-        "setup_phases": win.phases,
-        "hashes_done_gap": hashes_done_gap(win),
+        **drv.facts(win),
+        "setup_phases": {"devices": t_devices, **win.phases},
         "compiles_in_window": win.compiles_in_window,
         "compiles": meter.compiles,
         "compile_s": meter.compile_s,
         "cache_hits": meter.cache_hits,
-        "push_coalesced": win.coalesced,
         "stalled": win.stalls,
         "reference_s": reference_s,
         "run_s": time.perf_counter() - T_PROCESS,

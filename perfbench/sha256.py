@@ -137,3 +137,19 @@ def target_words(difficulty: int) -> tuple[int, ...]:
     ``d = 0`` clamps to all ones."""
     t = min(1 << (256 - difficulty), (1 << 256) - 1)
     return tuple((t >> (32 * (7 - i))) & M32 for i in range(8))
+
+
+def header_check(header80: bytes, prev_words, target_words, ops: Ops | None = None) -> bool:
+    """One header of a chain as the spec counts it: SHA-256d of its 80
+    bytes (three compressions), the target compare, and its previous
+    hash against the parent's digest ``prev_words``, word by word."""
+    o = ops or Ops()
+    block = pad(header80)
+    first = compress(compress(IV, block[:64], o), block[64:], o)
+    second = compress(IV, pad(struct.pack(">8I", *first)), o)
+    ok = below(second, target_words, o)
+    claimed = struct.unpack(">8I", header80[4:36])
+    link = o.eq(claimed[0], prev_words[0])
+    for c, p in zip(claimed[1:], prev_words[1:]):
+        link = o.and_(link, o.eq(c, p))
+    return ok and bool(link)

@@ -192,8 +192,9 @@ def report(tr: dict, reduced, data: dict, first: int, last: int, blocks: int) ->
     (``trace.load``), its reduction, the spans (``load``) and the window's
     edges (search calls ``first`` and ``last``) and block count."""
     from perfbench import trace
+    from perfbench.drivers import mine
 
-    edges = trace.search_spans(tr)
+    edges = trace.search_spans(tr, mine.SPAN)
     t0, t1 = edges[first][1], edges[last][1]
     found = spans(data)
     per_block = lambda names: (clipped_ns(found, names, t0, t1) / 1e6 / blocks  # noqa: E731
@@ -260,6 +261,7 @@ def main(argv=None) -> int:
     from perfbench import trace
     from perfbench.compile_meter import CompileMeter
     from perfbench.drive import drive
+    from perfbench.drivers import mine
 
     traffic = dict(cell.traffic, **(cell.traffic["rehearsal"] if args.rehearse else {}))
     calls = []  # each search_nonce call's MineStats, in call order
@@ -288,7 +290,7 @@ def main(argv=None) -> int:
         tr, data = trace.load(trace_dir), load(trace_dir)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    reduced = trace.reduce(tr, win.first, win.last)
+    reduced = trace.reduce(tr, mine.SPAN, win.first, win.last, mine.IDLE)
     out = {"workload": cell.name, "seed": args.seed,
            "device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                       "count": cell.chips},

@@ -1,13 +1,17 @@
-"""Adding a cell or a per-layer metric is adding files: the harness finds
-a new traffic file, a new workload entry and a new reader by name, and
-no file of the harness changes."""
+"""Adding a cell, a driver or a per-layer metric is adding files: the
+harness finds a new traffic file, a new driver, a new configuration, a
+new workload entry and a new reader by name, and no file of the harness
+changes."""
 
 import json
 import pathlib
 import shutil
+import textwrap
 import types
 
-from perfbench import cell, view
+import pytest
+
+from perfbench import cell, run, view
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
@@ -23,7 +27,7 @@ def test_new_workload_and_metric_are_found_by_name(tmp_path):
     root = _checkout(tmp_path)
     before = {p.relative_to(root) : p.read_bytes() for p in (root / "perfbench").rglob("*.py")}
     (root / "perfbench/traffic/d24.json").write_text(json.dumps(
-        {"difficulty": 24, "warmup_blocks": 3, "first_hit_sample": 4,
+        {"driver": "mine", "difficulty": 24, "warmup_blocks": 3, "first_hit_sample": 4,
          "rehearsal": {"difficulty": 9, "warmup_blocks": 2, "first_hit_sample": 100}}))
     (root / "perfbench/metrics/blocks_seen.py").write_text(
         "def read(run):\n    return float(run.blocks)\n")
@@ -47,6 +51,109 @@ def test_new_workload_and_metric_are_found_by_name(tmp_path):
     after = {p.relative_to(root): p.read_bytes() for p in (root / "perfbench").rglob("*.py")
              if p.name != "blocks_seen.py"}
     assert after == before
+
+
+#: A driver with no device and no program: it echoes the traffic's word.
+ECHO = textwrap.dedent('''
+    import dataclasses
+    import time
+    import types
+
+    SPAN = "echo."
+    IDLE = ("in an echo", "between echoes")
+
+
+    @dataclasses.dataclass
+    class Window:
+        echoes: list
+        setup_s: float
+        first: int = 0
+        last: int = 1
+        compiles_in_window: int = 0
+        phases: dict = dataclasses.field(default_factory=dict)
+        stalls: list = dataclasses.field(default_factory=list)
+
+
+    def drive(cell, traffic, seconds, *, rehearse, t_process, trace_dir, patch, meter):
+        win = Window([traffic["word"]] * traffic["count"], time.perf_counter() - t_process)
+        if patch is not None:
+            patch(win)
+        return win
+
+
+    def compare(win, seed, devices, traffic):
+        wrong = sum(e != traffic["word"] for e in win.echoes)
+        return {"echo_diff": {"value": wrong, "limit": 0}}, len(win.echoes), wrong
+
+
+    def view(win, cell, reduced, peak):
+        return types.SimpleNamespace(setup_s=win.setup_s, echoes=len(win.echoes), window_s=2.0,
+                                     trace=reduced)
+
+
+    def facts(win):
+        return {"echoes": len(win.echoes)}
+
+
+    def control(win):
+        win.echoes[-1] = win.echoes[-1].upper()
+''')
+
+
+def _add_echo_cell(root, traffic: dict) -> None:
+    (root / "perfbench/drivers/echo.py").write_text(ECHO)
+    (root / "perfbench/configs/echo-1.json").write_text(json.dumps({"name": "echo-1"}))
+    (root / "perfbench/traffic/echo.json").write_text(json.dumps(traffic))
+    (root / "perfbench/metrics/echoes_per_s.py").write_text(
+        "def read(run):\n    return run.echoes / run.window_s\n")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "echo-1", "source": "a test", "reduced": [],
+                             "file": "perfbench/configs/echo-1.json", "why": "a test"})
+    bench["workloads"].append({"name": "echo-cell", "config": "echo-1", "traffic": "echo",
+                               "chips": 1, "why": "a test"})
+    bench["end_to_end"].append({"name": "echoes_per_s", "unit": "echoes/s", "better": "higher",
+                                "bound": 0.01, "source": "host_clock", "workloads": ["echo-cell"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def test_new_driver_config_traffic_and_metric_are_found_by_name(tmp_path, capsys, monkeypatch):
+    root = _checkout(tmp_path)
+    before = {p.relative_to(root): p.read_bytes() for p in (root / "perfbench").rglob("*.py")}
+    _add_echo_cell(root, {"driver": "echo", "word": "hi", "count": 5,
+                          "rehearsal": {"count": 3}})
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(root / ".jax_cache"))
+
+    c = cell.load("echo-cell", root)
+    assert c.driver == "echo" and c.config == {"name": "echo-1"}
+    assert {m["name"] for m in c.end_to_end} == {"echoes_per_s", "setup_s"}
+    drv = cell.driver(c)
+    win = drv.drive(c, c.traffic, 1.0, rehearse=False, t_process=0.0, trace_dir=None,
+                    patch=None, meter=None)
+    metrics = cell.read_metrics(c, c.end_to_end, drv.view(win, c, None, {}))
+    assert metrics["echoes_per_s"] == {"value": 2.5, "unit": "echoes/s"}
+    # The whole run goes through the new driver, and so does the control.
+    for patch, correct in ((None, True), (drv.control, False)):
+        assert run.main(["--workload", "echo-cell", "--seed", "5", "--seconds", "1",
+                         "--rehearse"], patch=patch, root=root) == 0
+        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert out["correct"] is correct and out["attempted"] == 3
+        assert out["facts"]["echoes"] == 3 and list(out)[-1] == "checks"
+    after = {p.relative_to(root): p.read_bytes() for p in (root / "perfbench").rglob("*.py")
+             if p.name not in ("echo.py", "echoes_per_s.py")}
+    assert after == before
+
+
+@pytest.mark.parametrize("traffic", [{"word": "hi", "count": 5}, {"driver": "none", "word": "hi"}],
+                         ids=["no driver key", "no such driver"])
+def test_a_traffic_without_its_driver_is_refused_by_name(tmp_path, capsys, traffic):
+    root = _checkout(tmp_path)
+    _add_echo_cell(root, traffic)
+    with pytest.raises(KeyError, match="driver"):
+        cell.load("echo-cell", root)
+    assert run.main(["--workload", "echo-cell", "--seed", "5", "--seconds", "1", "--rehearse"],
+                    root=root) == 2
+    captured = capsys.readouterr()
+    assert captured.out == "" and "driver" in captured.err and "'echo'" in captured.err
 
 
 def test_a_reader_with_nothing_to_read_is_left_out(tmp_path):

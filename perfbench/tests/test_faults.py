@@ -2,14 +2,16 @@
 
 Each fault a cell can have, planted in the program below the harness, in
 a rehearsal run (CPU, rehearsal sizes, the Pallas kernel in the
-interpreter); and the control of ``perfbench/control.py``.
+interpreter); and the control that each driver supplies to
+``perfbench/control.py``.
 """
 
 import json
 
 import pytest
 
-from perfbench import control, run
+from perfbench import run
+from perfbench.drivers import mine, replay
 
 
 def _run(capsys, cell, patch, seconds="2"):
@@ -74,7 +76,7 @@ def unchanged(node, mp):
 
 
 def second_hit(node, mp):
-    control.second_hit(node)
+    mine.control(node)
 
 
 @pytest.mark.parametrize("cell", ["solo-d28", "pod-d28"])
@@ -105,3 +107,71 @@ def test_exchange_between_chips_left_out(capsys, monkeypatch):
 
 def test_sound_run_in_process_is_correct(capsys):
     assert _run(capsys, "solo-d28", None)["correct"] is True
+
+
+def _wrap_scan(mp, change):
+    """Patch the program's chain scan: ``change(step, words3, target,
+    difficulty, segment)`` stands in for each call of the built step."""
+    from p1_tpu.hashx import jax_sha256
+
+    build = jax_sha256.jit_verify_chain_scan
+
+    def patched(n_segments, segment, platform=None, unroll=None):
+        step = build(n_segments, segment, platform, unroll)
+        return lambda words3, target, d: change(step, words3, target, d, segment)
+
+    mp.setattr(jax_sha256, "jit_verify_chain_scan", patched)
+
+
+def scan_answer_altered(win, mp):
+    """Each segment's first invalid index is off by one where the scan
+    produces it."""
+    import jax.numpy as jnp
+
+    def change(step, words3, target, d, segment):
+        idx = step(words3, target, d)
+        return jnp.where(idx < segment, idx + 1, idx)
+
+    _wrap_scan(mp, change)
+
+
+def scan_half_left_out(win, mp):
+    """The scan's second half of the segments reads clean, unverified."""
+    import jax.numpy as jnp
+
+    def change(step, words3, target, d, segment):
+        idx = step(words3, target, d)
+        keep = jnp.arange(idx.shape[0]) < idx.shape[0] // 2
+        return jnp.where(keep, idx, segment)
+
+    _wrap_scan(mp, change)
+
+
+def replay_unchanged(win, mp):
+    """Every replay hands back the first replay's verdict."""
+    verify, first = win.verify, []
+
+    def same(headers):
+        if not first:
+            first.append(verify(headers))
+        return first[0]
+
+    win.verify = same
+
+
+def skip_last_segment(win, mp):
+    replay.control(win, mp.setattr)
+
+
+@pytest.mark.parametrize("fault", [scan_answer_altered, scan_half_left_out, replay_unchanged,
+                                   skip_last_segment], ids=lambda f: f.__name__)
+def test_replay_fault_is_not_correct(capsys, monkeypatch, fault):
+    out = _run(capsys, "replay-mainnet", lambda win: fault(win, monkeypatch))
+    assert out["correct"] is False, out["checks"]
+    assert out["checks"]["missed_corruption"]["value"] > 0, out
+    assert out["failed"] > 0
+
+
+def test_sound_replay_in_process_is_correct(capsys):
+    out = _run(capsys, "replay-mainnet", None)
+    assert out["correct"] is True and out["failed"] == 0, out["checks"]

@@ -34,3 +34,17 @@ def test_target_compare_matches_integer_compare(d):
         digest, ok = sha256.candidate(header76, nonce, sha256.target_words(d))
         want = d == 0 or int.from_bytes(digest, "big") < (1 << (256 - d))
         assert ok == want
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_counted_header_check_counts_the_spec(seed):
+    rng = random.Random(seed)
+    parent = rng.randbytes(80)
+    prev = _sha256d(parent)
+    header = rng.randbytes(4) + prev + rng.randbytes(44)
+    words = struct.unpack(">8I", prev)
+    for target, want in ((sha256.target_words(0), True), (sha256.target_words(255), False)):
+        ops = sha256.Ops()
+        assert sha256.header_check(header, words, target, ops) is want
+        assert ops.n == opcount.header_verify_ops() == 6932
+    assert not sha256.header_check(header, words[:7] + (words[7] ^ 1,), sha256.target_words(0))

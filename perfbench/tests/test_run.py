@@ -1,8 +1,10 @@
 """Every cell end to end on the CPU at rehearsal size, and the refusals.
 
-The rehearsal drives the whole run (node, host loop, Pallas kernel in the
-interpreter, the pod's mesh on four virtual devices, push client, store,
-reference) and prints no metric.  Without ``--rehearse`` the command
+The rehearsal drives the whole run and prints no metric: for driver
+``mine`` the node, host loop, Pallas kernel in the interpreter, the pod's
+mesh on four virtual devices, push client, store and reference; for
+driver ``replay`` the chain's build, the device scan on the CPU, the
+seeded corruptions and the reference.  Without ``--rehearse`` the command
 refuses a host with no TPU, and a checkout that holds only the benchmark.
 """
 
@@ -16,7 +18,10 @@ import sys
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
-CELLS = [w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCH["workloads"]]
+DRIVER = {w["name"]: json.loads((ROOT / "perfbench/traffic" / f"{w['traffic']}.json").read_text())
+          ["driver"] for w in BENCH["workloads"]}
 
 
 def _run(args, cwd=ROOT, platform="cpu"):
@@ -41,11 +46,17 @@ def test_rehearsal_is_correct_and_prints_no_metric(cell):
         assert out["device"]["platform"] == "cpu"
         assert list(out)[-1] == "checks"
         assert all(c == {"value": 0, "limit": 0} for c in out["checks"].values())
-        assert "check not_first 0 limit 0" in proc.stderr
-        # The harness's count of useful hashes, from the sealed headers,
-        # agrees with the program's own.
-        assert out["facts"]["hashes_done_gap"] == 0
         assert out["facts"]["compiles_in_window"] == 0
+        if DRIVER[cell] == "mine":
+            assert "check not_first 0 limit 0" in proc.stderr
+            # The harness's count of useful hashes, from the sealed
+            # headers, agrees with the program's own.
+            assert out["facts"]["hashes_done_gap"] == 0
+        else:
+            assert "check missed_corruption 0 limit 0" in proc.stderr
+            # Every seeded flip read back at the reference's index.
+            assert all(got == want for *_, got, want in out["facts"]["corruptions"])
+            assert len(out["facts"]["corruptions"]) == 2
 
 
 def test_every_seed_seals_the_same_chain():

@@ -8,6 +8,7 @@ import pathlib
 import pytest
 
 from perfbench import spans, trace
+from perfbench.drivers import mine
 
 FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures"
 KERNEL = '%sha256d_search.1 = s32[1]{0:T(128)} custom-call(u32[8]{0} %a), custom_call_target="tpu_custom_call"'
@@ -52,7 +53,7 @@ def _hand_made():
 
 def test_idle_splits_exactly_among_the_innermost_spans():
     tr, data = _hand_made()
-    r = trace.reduce(tr, 3, 5)
+    r = trace.reduce(tr, mine.SPAN, 3, 5, mine.IDLE)
     rows = dict(spans.idle_by_span(r, spans.spans(data), 100, 1100))
     # Device 0 idles 400..600 and 1000..1050, device 1 380..600 and
     # 900..1050; rows are the mean over the two devices.
@@ -87,7 +88,7 @@ def test_join_is_first_in_first_out_with_a_launch_from_before_the_trace():
 
 def test_abandoned_share_and_per_block_readings():
     tr, data = _hand_made()
-    out = spans.report(tr, trace.reduce(tr, 3, 5), data, 3, 5, blocks=2)
+    out = spans.report(tr, trace.reduce(tr, mine.SPAN, 3, 5, mine.IDLE), data, 3, 5, blocks=2)
     # Window kernel time: device 0 300 + 400 + 50, device 1 280 + 300 + 50;
     # abandoned: the first and last of each.
     assert out["abandoned_kernel_share"] == pytest.approx(100 * (350 + 330) / (750 + 630))
@@ -136,11 +137,11 @@ def test_recorded_traces_reduce_as_before(name, busy_ns, ops, idle_gaps):
     """The traces recorded on the chip before the program had spans give
     the same numbers, and all their idle time is ``no program span``."""
     tr = json.loads((FIXTURES / f"trace_{name}.json").read_text())
-    r = trace.reduce(tr, tr["first"], tr["last"])
+    r = trace.reduce(tr, mine.SPAN, tr["first"], tr["last"], mine.IDLE)
     assert r.devices[0].busy_ns == busy_ns
     bd = r.breakdown()
     assert bd["device_ops"][:2] == ops and bd["idle_gaps"] == idle_gaps
-    edges = trace.search_spans(tr)
+    edges = trace.search_spans(tr, mine.SPAN)
     t0, t1 = edges[tr["first"]][1], edges[tr["last"]][1]
     rows = spans.idle_by_span(r, [], t0, t1)
     assert [k for k, _ in rows] == [spans.NO_SPAN]

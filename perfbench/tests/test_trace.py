@@ -8,6 +8,7 @@ import types
 import pytest
 
 from perfbench import layers, trace
+from perfbench.drivers import mine
 
 FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures"
 KERNEL = '%step.1 = s32[1]{0:T(128)} custom-call(u32[8]{0} %a), custom_call_target="tpu_custom_call"'
@@ -37,7 +38,7 @@ def _hand_made():
 
 
 def test_busy_union_idle_and_gaps():
-    r = trace.reduce(_hand_made(), 3, 5)
+    r = trace.reduce(_hand_made(), mine.SPAN, 3, 5, mine.IDLE)
     assert r.window_ns == 1000
     d0, d1 = r.devices
     assert d0.busy_ns == (450 - 100) + (1010 - 600) + (1100 - 1050)
@@ -56,7 +57,7 @@ def test_busy_union_idle_and_gaps():
 
 
 def test_layer_readings_on_the_hand_made_trace():
-    r = trace.reduce(_hand_made(), 3, 5)
+    r = trace.reduce(_hand_made(), mine.SPAN, 3, 5, mine.IDLE)
     run = types.SimpleNamespace(trace=r, blocks=2, hashes=10**9, window_s=1e-6, chips=2,
                                 ops_per_hash=4621, peak_ops_per_s=6e12)
     assert layers.launches_per_block(run) == 1.5
@@ -68,9 +69,9 @@ def test_layer_readings_on_the_hand_made_trace():
 
 def test_no_device_or_no_window_edge_reads_nothing():
     tr = _hand_made()
-    assert trace.reduce(tr, 3, 9) is None
+    assert trace.reduce(tr, mine.SPAN, 3, 9, mine.IDLE) is None
     tr["planes"] = [p for p in tr["planes"] if not p["name"].startswith("/device")]
-    assert trace.reduce(tr, 3, 5) is None
+    assert trace.reduce(tr, mine.SPAN, 3, 5, mine.IDLE) is None
     run = types.SimpleNamespace(trace=None, blocks=3)
     assert layers.launches_per_block(run) is None
     assert layers.kernel_roofline(run) is None
@@ -82,8 +83,8 @@ def test_a_renamed_kernel_reads_nothing_and_says_so(capsys):
     for plane in tr["planes"][:2]:
         for ev in plane["lines"][0]["events"]:
             ev[0] = ev[0].replace("tpu_custom_call", "renamed")
-    run = types.SimpleNamespace(trace=trace.reduce(tr, 3, 5), blocks=2, hashes=10**9,
-                                ops_per_hash=4621, peak_ops_per_s=6e12)
+    run = types.SimpleNamespace(trace=trace.reduce(tr, mine.SPAN, 3, 5, mine.IDLE), blocks=2,
+                                hashes=10**9, ops_per_hash=4621, peak_ops_per_s=6e12)
     assert layers.kernel_roofline(run) is None
     assert layers.launches_per_block(run) is None
     assert capsys.readouterr().err.count("but no") == 2
@@ -91,7 +92,7 @@ def test_a_renamed_kernel_reads_nothing_and_says_so(capsys):
 
 def _recorded(name):
     tr = json.loads((FIXTURES / f"trace_{name}.json").read_text())
-    return trace.reduce(tr, tr["first"], tr["last"])
+    return trace.reduce(tr, mine.SPAN, tr["first"], tr["last"], mine.IDLE)
 
 
 @pytest.mark.parametrize("name, launches, idle", [
@@ -110,3 +111,31 @@ def test_recorded_trace(name, launches, idle):
     assert all(e - s <= r.window_ns for _, s, e in dev.ops)
     assert r.breakdown()["device_ops"][0][0] == "%step.1 custom-call tpu_custom_call"
     assert not r.events(dev, layers.is_collective)
+
+
+def test_recorded_replay_trace(capsys):
+    """Three replays of the 870,000-header chain on the chip, the device
+    ops kept where no other op holds them: the scan's ``while`` loop over
+    ``u32[107,8192,20]`` and a few small ops."""
+    from perfbench import cell
+    from perfbench.drivers import replay
+
+    tr = json.loads((FIXTURES / "trace_replay-mainnet.json").read_text())
+    r = trace.reduce(tr, replay.SPAN, tr["first"], tr["last"], replay.IDLE)
+    (dev,) = r.devices
+    scan_ns = replay.scan_ns(r, 107, 8192)
+    assert scan_ns == pytest.approx(40063977) and dev.busy_ns == pytest.approx(40066317)
+    assert r.breakdown()["device_ops"][0][0] == "%while.248 while"
+    assert [k for k, _ in r.breakdown()["idle_gaps"]] == [replay.IDLE[0]]
+    run = replay.View(chips=1, setup_s=1.0, window_s=r.window_ns / 1e9, replays=3,
+                      headers=3 * 870000, trace=r, scan_ns=scan_ns, peak_ops_per_s=5.98e12)
+    read = {name: cell.reader(cell.HERE / "metrics", name)(run) for name in
+            ("verify_scan_roofline", "host_ms_per_replay", "device_idle_share.replay")}
+    assert read["verify_scan_roofline"] == pytest.approx(
+        100 * 3 * 870000 * 6932 / (40063977e-9 * 5.98e12))
+    assert 7 < read["verify_scan_roofline"] < 8
+    assert read["host_ms_per_replay"] == pytest.approx((r.window_ns - 40063977) / 1e6 / 3)
+    assert read["device_idle_share.replay"] == pytest.approx(100 * (1 - 40066317 / r.window_ns))
+    # Another chain's shape is not this scan: nothing to read, and said so.
+    assert replay.scan_ns(r, 106, 8192) is None
+    assert "no chain scan" in capsys.readouterr().err

@@ -6,10 +6,11 @@ profiler gives them.  ``reduce`` then works on that plain form only, so
 ``tests/test_trace.py`` checks it on a small trace recorded on the chip
 and kept as JSON.
 
-The window is marked by the harness itself: every call of the miner's
-``search_nonce`` runs inside a host annotation ``perfbench.search.<i>``
-(``i`` = the call's index), and the window runs from the end of the call
-that sealed its first block to the end of the call that sealed its last.
+The window is marked by the harness itself: every timed call of the
+cell's driver runs inside a host annotation ``<span><i>`` (``i`` = the
+call's index; ``perfbench.search.`` for the miner's ``search_nonce``),
+and the window runs from the end of the call that opened it to the end
+of the call that closed it.
 """
 
 from __future__ import annotations
@@ -18,7 +19,6 @@ import dataclasses
 import glob
 import re
 
-SEARCH_SPAN = "perfbench.search."
 _DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 #: The line of a TPU plane that holds one event per device operation.
 OPS_LINE = "XLA Ops"
@@ -59,16 +59,16 @@ def ops(plane: dict) -> list:
     return []
 
 
-def search_spans(tr: dict) -> dict[int, tuple[float, float]]:
-    """Call index -> (start_ns, end_ns) of the harness's search spans."""
+def search_spans(tr: dict, span: str) -> dict[int, tuple[float, float]]:
+    """Call index -> (start_ns, end_ns) of the harness's spans ``<span><i>``."""
     spans = {}
     for plane in tr["planes"]:
         if _DEVICE.match(plane["name"]):
             continue
         for line in plane["lines"]:
             for name, start, dur in line["events"]:
-                if name.startswith(SEARCH_SPAN):
-                    spans[int(name[len(SEARCH_SPAN):])] = (start, start + dur)
+                if name.startswith(span):
+                    spans[int(name[len(span):])] = (start, start + dur)
     return spans
 
 
@@ -115,7 +115,8 @@ class Device:
 class Reduced:
     window_ns: float
     devices: list[Device]
-    host_search: list[tuple[float, float]]  # search spans, clipped
+    host_search: list[tuple[float, float]]  # the driver's call spans, clipped
+    idle_labels: tuple[str, str]  # idle inside a call, and outside every call
 
     def events(self, dev: Device, match) -> list:
         return [o for o in dev.ops if match(o[0])]
@@ -137,8 +138,7 @@ class Reduced:
             for s, e in dev.gaps:
                 mid = (s + e) / 2
                 inside = any(a <= mid <= b for a, b in self.host_search)
-                key = ("host in search_nonce: dispatch and readback" if inside
-                       else "host outside search_nonce: node assemble, post-seal, push")
+                key = self.idle_labels[0] if inside else self.idle_labels[1]
                 by_host[key] = by_host.get(key, 0.0) + (e - s) / n
         rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]  # noqa: E731
         return {
@@ -147,11 +147,12 @@ class Reduced:
         }
 
 
-def reduce(tr: dict, first_call: int, last_call: int) -> Reduced | None:
-    """Clip every TPU's ops to the window that runs from the end of search
-    call ``first_call`` to the end of call ``last_call``.  None where the
-    trace holds no TPU or lacks either edge."""
-    spans = search_spans(tr)
+def reduce(tr: dict, span: str, first_call: int, last_call: int,
+           idle_labels: tuple[str, str]) -> Reduced | None:
+    """Clip every TPU's ops to the window that runs from the end of call
+    ``first_call`` to the end of call ``last_call`` (spans ``<span><i>``).
+    None where the trace holds no TPU or lacks either edge."""
+    spans = search_spans(tr, span)
     planes = device_planes(tr)
     if not planes or first_call not in spans or last_call not in spans:
         return None
@@ -165,4 +166,4 @@ def reduce(tr: dict, first_call: int, last_call: int) -> Reduced | None:
                 if edges[i + 1] > edges[i]]
         devices.append(Device(dev_id, sum(e - s for s, e in busy), clipped, gaps))
     host = [(max(a, t0), min(b, t1)) for a, b in spans.values() if b > t0 and a < t1]
-    return Reduced(t1 - t0, devices, host)
+    return Reduced(t1 - t0, devices, host, idle_labels)
