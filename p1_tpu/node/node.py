@@ -1226,7 +1226,10 @@ class Node:
         path = self._mempool_path()
         if path is None or not path.exists():
             return
-        restored, dropped = load_mempool(self.mempool, path)
+        with self.telemetry.span("mempool.restore_s", height=self.chain.height):
+            restored, dropped = load_mempool(self.mempool, path)
+        self.telemetry.counter("mempool.restored").value += restored
+        self.telemetry.counter("mempool.restore_dropped").value += dropped
         if restored or dropped:
             self.log.info(
                 "mempool resumed: %d restored, %d dropped on revalidation",
@@ -4829,7 +4832,8 @@ class Node:
                     self._announce_tip = True
                 if res.removed:
                     self.metrics.reorgs += 1
-                self.mempool.apply_block_delta(res.removed, res.added)
+                with self.telemetry.span("mempool.evict_s", **tag):
+                    self.mempool.apply_block_delta(res.removed, res.added)
                 self._abort_inflight_search()
                 tip = self.chain.tip
                 self.log.info(
@@ -4949,10 +4953,9 @@ class Node:
         height = self.chain.height_of(parent.block_hash()) + 1
         coinbase = Transaction.coinbase(self.miner_id, height)
         if on_tip:
-            txs = (
-                coinbase,
-                *self.mempool.select(max(0, self.config.max_block_txs - 1)),
-            )
+            with self.telemetry.span("mempool.select_s", height=height):
+                picked = self.mempool.select(max(0, self.config.max_block_txs - 1))
+            txs = (coinbase, *picked)
         else:
             # Policy fork off a poisoned suffix: pool selection is only
             # guaranteed connectable against the TIP's ledger, so carry
@@ -5045,6 +5048,7 @@ class Node:
             with tel.span("mine.post_seal_s", height=height):
                 block = Block(sealed, candidate.txs)
                 self.metrics.blocks_mined += 1
+                tel.gauge("mine.block_txs").value = len(block.txs) - 1
                 self.metrics.last_block_time_s = self.clock.monotonic() - t0
                 self.log.info(
                     "mined height=%d nonce=%d txs=%d t=%.3fs hps=%.0f",
@@ -5109,11 +5113,13 @@ class Node:
         mirroring them at verify time would put a registry write on the
         hot validation path for a number only scrapes read.  Gauges
         (not counters) because the registry copy is a mirror, not the
-        source of truth."""
+        source of truth.  ``mempool.pending`` is the pool's size, read
+        the same way."""
         for name, value in (
             ("validation.sigs_serial", keys.STATS.serial),
             ("validation.sigs_batched", keys.STATS.batched),
             ("validation.sigs_cached", self.sig_cache.hits),
+            ("mempool.pending", len(self.mempool)),
             *(
                 (f"validation.backend.{b}", keys.STATS.backends.get(b, 0))
                 for b in keys.SIG_BACKENDS

@@ -1,0 +1,7 @@
+"""Time inside the program's ``p1.mempool.evict`` spans (each connected
+block's confirmed transfers leaving the pool) over the window, per window
+block, in ms; None where the trace holds no such span."""
+
+
+def read(run):
+    return (run.spans_ms_per_block or {}).get("p1.mempool.evict")

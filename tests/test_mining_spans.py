@@ -240,3 +240,135 @@ class TestNodeTrace:
         snap = node.telemetry_snapshot()
         assert not [n for n in snap["counters"] if n.startswith("search.")]
         assert snap["histograms"] == {}
+
+
+def _pool_file(store: str, difficulty: int, senders: int = 4, per: int = 6,
+               unaffordable: int = 2) -> tuple[int, int]:
+    """Write ``<store>.mempool``: ``senders * per`` zero-value transfers,
+    seqs gap-free from 0, and ``unaffordable`` more that pay a fee their
+    empty accounts cannot cover.  Returns (restorable, unaffordable)."""
+    from p1_tpu.core.genesis import make_genesis
+    from p1_tpu.core.keys import Keypair
+    from p1_tpu.core.tx import Transaction
+    from p1_tpu.mempool import dump_mempool
+
+    tag = make_genesis(difficulty).block_hash()
+    keys = [Keypair.from_seed_text(f"pool-spans/{i}") for i in range(senders)]
+    rows = [(Transaction.transfer(keys[i % senders], keys[(i + 1) % senders].account, 0, 0,
+                                  i // senders, chain=tag), 0.0)
+            for i in range(senders * per)]
+    poor = Keypair.from_seed_text("pool-spans/poor")
+    rows += [(Transaction.transfer(poor, keys[0].account, 0, 1, i, chain=tag), 0.0)
+             for i in range(unaffordable)]
+    with open(f"{store}.mempool", "wb") as fh:
+        fh.write(dump_mempool(rows))
+    return senders * per, unaffordable
+
+
+def _pool_node(tmp_path, telemetry: bool, max_block_txs: int = 4, difficulty: int = 8):
+    from p1_tpu.config import NodeConfig
+    from p1_tpu.node import Node
+
+    store = str(tmp_path / "chain.dat")
+    counts = _pool_file(store, difficulty)
+    node = Node(NodeConfig(difficulty=difficulty, backend="cpu", host="127.0.0.1", port=0,
+                           store_path=store, miner_id="pool-spans-miner",
+                           max_block_txs=max_block_txs, telemetry=telemetry))
+    return node, counts
+
+
+class TestPoolSpans:
+    """Selection, eviction and restore: spans with the block's height, and
+    counters; recording changes nothing the pool does."""
+
+    def test_spans_nest_in_their_blocks_and_counters_hold(self, tmp_path):
+        from p1_tpu.config import NodeConfig
+        from p1_tpu.node import Node
+
+        store = str(tmp_path / "chain.dat")
+        restorable, unaffordable = _pool_file(store, 10, per=30)
+        be = JaxBackend(batch=4096)
+        node = Node(NodeConfig(difficulty=10, backend="jax", host="127.0.0.1", port=0,
+                               store_path=store, miner_id="pool-spans-miner", max_block_txs=4),
+                    miner=Miner(backend=be, chunk=1 << 14))
+
+        async def main():
+            await node.start()
+            try:
+                for _ in range(600):
+                    if node.chain.height >= 3:
+                        break
+                    await asyncio.sleep(0.05)
+            finally:
+                await node.stop_mining()
+                snap = node.telemetry_snapshot()
+                await node.stop()
+            return snap
+
+        out = {}
+        events = _capture(tmp_path / "trace", lambda: out.setdefault("snap", asyncio.run(main())))
+        snap = out["snap"]
+        assert node.chain.height >= 3
+        by_name: dict[str, list] = {}
+        for e in events:
+            by_name.setdefault(e[0], []).append(e)
+        (restore,) = by_name["p1.mempool.restore"]
+        assert restore[3] == {"height": 0}
+        assemble = {e[3]["height"]: e for e in by_name["p1.mine.assemble"]}
+        selects = {e[3]["height"]: e for e in by_name["p1.mempool.select"]}
+        assert set(selects) == set(assemble) and restore[2] <= min(s[1] for s in selects.values())
+        for h, e in selects.items():
+            assert _within(e, assemble[h]), h
+        sealed = {e[3]["height"]: e for e in by_name["p1.mine.post_seal"]}
+        evicts = {e[3]["height"]: e for e in by_name["p1.mempool.evict"]}
+        assert set(evicts) == set(sealed) and len(evicts) >= 3
+        for h, e in evicts.items():
+            assert _within(e, sealed[h]), h
+        assert snap["counters"]["mempool.restored"] == restorable
+        assert snap["counters"]["mempool.restore_dropped"] == unaffordable
+        mined = [b for b in node.chain.main_chain()][1:]
+        assert [len(b.txs) for b in mined[:3]] == [4, 4, 4]
+        assert snap["gauges"]["mine.block_txs"] == len(mined[-1].txs) - 1 == 3
+        assert snap["gauges"]["mempool.pending"] == len(node.mempool) == \
+            restorable - 3 * len(mined)
+        hists = snap["histograms"]
+        assert hists["mempool.select_s"]["count"] == len(selects)
+        assert hists["mempool.evict_s"]["count"] == len(evicts)
+        assert hists["mempool.restore_s"]["count"] == 1
+
+    @pytest.mark.parametrize("telemetry", [True, False])
+    def test_restored_counts_the_records_restored(self, tmp_path, telemetry):
+        node, (restorable, unaffordable) = _pool_node(tmp_path, telemetry)
+        node._load_mempool()
+        counters = node.telemetry_snapshot()["counters"]
+        assert counters["mempool.restored"] == len(node.mempool) == restorable
+        assert counters["mempool.restore_dropped"] == unaffordable
+        assert ("mempool.restore_s" in node.telemetry.histograms) is telemetry
+
+    def test_select_and_eviction_are_the_same_with_telemetry_off(self, tmp_path):
+        from p1_tpu.core import Block
+
+        def mine(telemetry: bool) -> list:
+            node, _ = _pool_node(tmp_path / str(telemetry), telemetry)
+            node._load_mempool()
+            seen = []
+
+            async def main():
+                for height in range(1, 5):
+                    candidate = node._assemble()
+                    sealed = node.miner.search_nonce(candidate.header)
+                    await node._handle_block(Block(sealed, candidate.txs), origin=None,
+                                             height=height)
+                    pool = sorted(tx.serialize() for tx in node.mempool._txs.values())
+                    seen.append(([tx.serialize() for tx in candidate.txs], pool))
+
+            asyncio.run(main())
+            assert node.chain.height == 4
+            return seen
+
+        (tmp_path / "True").mkdir()
+        (tmp_path / "False").mkdir()
+        on, off = mine(True), mine(False)
+        assert on == off
+        assert [len(txs) for txs, _ in on] == [4, 4, 4, 4]
+        assert [len(pool) for _, pool in on] == [21, 18, 15, 12]
