@@ -450,6 +450,98 @@ class TestReplay:
         device = replay_device(bad, segment=16)
         assert host.first_invalid == device.first_invalid == 41
 
+    @pytest.mark.parametrize(
+        "n, flip",
+        [
+            (1, None),  # one header
+            (10, None),  # n < segment: no full segment, the tail alone
+            (32, None),  # an exact multiple: no tail
+            (40, None),  # two full segments and a partial tail
+            (10, (9, 76, 0)),  # the tail alone, its last header's nonce
+            (40, (35, 76, 5)),  # in the tail segment, a nonce bit
+            (40, (39, 4, 3)),  # the last real header, a prev_hash bit
+            (40, (27, 20, 7)),  # in the last full segment, a prev_hash bit
+            (32, (31, 79, 1)),  # the last header of the last full segment
+        ],
+        ids=[
+            "one_header",
+            "under_one_segment",
+            "exact_multiple",
+            "partial_tail",
+            "flip_under_one_segment",
+            "flip_in_tail",
+            "flip_in_last_real_header",
+            "flip_in_last_full_segment",
+            "flip_in_last_full_segment_no_tail",
+        ],
+    )
+    def test_device_matches_host_at_every_length(self, headers, n, flip):
+        # Segment 16: the body's full segments and the padded tail are
+        # uploaded apart and joined on the device; a single-bit flip
+        # (index, byte offset, bit) lands on either side of the join.
+        chain = list(headers[:n])
+        if flip is not None:
+            index, offset, bit = flip
+            raw = bytearray(chain[index].serialize())
+            raw[offset] ^= 1 << bit
+            chain[index] = BlockHeader.deserialize(bytes(raw))
+        host = replay_host(chain)
+        device = replay_device(chain, segment=16)
+        assert (device.valid, device.first_invalid) == (
+            host.valid,
+            host.first_invalid,
+        )
+        assert host.valid == (flip is None)
+        assert device.n_headers == n
+
+    def test_device_scan_gets_big_endian_padded_words(self, headers, monkeypatch):
+        # The contract the benchmark's control and fault tests patch into:
+        # the scan step built by ``jax_sha256.jit_verify_chain_scan`` (looked
+        # up at call time) gets u32[S, segment, 20] header words, big-endian,
+        # the pad rows byte-copies of the last real header.
+        import jax
+        import numpy as np
+
+        from p1_tpu.chain import pack_headers
+        from p1_tpu.hashx import jax_sha256
+
+        build, seen = jax_sha256.jit_verify_chain_scan, []
+
+        def spying(n_segments, segment, platform=None, unroll=None):
+            step = build(n_segments, segment, platform, unroll)
+
+            def spy(words3, target, difficulty):
+                seen.append(np.asarray(words3))
+                return step(words3, target, difficulty)
+
+            return spy
+
+        monkeypatch.setattr(jax_sha256, "jit_verify_chain_scan", spying)
+        chain = headers[:40]
+        assert replay_device(chain, segment=16).valid
+        (words3,) = seen
+        assert words3.shape == (3, 16, 20) and words3.dtype == np.uint32
+        rows = words3.reshape(-1, 20)
+        np.testing.assert_array_equal(
+            rows[:40], np.frombuffer(pack_headers(chain), ">u4").reshape(40, 20)
+        )
+        assert (rows[40:] == rows[39]).all()
+
+        # Another length with the same segment count compiles nothing new.
+        compiles = []
+
+        def count(event, secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        jax.monitoring.register_event_duration_secs_listener(count)
+        try:
+            assert replay_device(headers[:36], segment=16).valid
+        finally:
+            jax.monitoring.unregister_event_duration_listener(count)
+        assert len(seen) == 2 and seen[1].shape == (3, 16, 20)
+        assert compiles == []
+
 
 class TestPersistence:
     def test_roundtrip(self, chain_blocks, tmp_path):

@@ -112,3 +112,15 @@ def test_replay_scan_config3(topo):
     args = _shapes(SingleDeviceSharding(topo.devices[0]), (2, 8192, 20), (8,), ())
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_replay_staging_mainnet(topo):
+    # The byte swap and join that put 870,000 uploaded headers (106 full
+    # segments and a padded tail) into the scan's words: one fusion, no
+    # loop, so the trace's only u32[107,8192,20] ``while`` stays the scan.
+    from p1_tpu.chain.replay import _stage_words
+
+    args = _shapes(SingleDeviceSharding(topo.devices[0]), (106, 8192, 20), (1, 8192, 20))
+    compiled = jax.jit(_stage_words).lower(*args).compile()
+    assert compiled.out_info.shape == (107, 8192, 20)
+    assert "while" not in compiled.as_text()
