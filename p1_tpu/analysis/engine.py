@@ -77,8 +77,8 @@ class Report:
     parse_errors: list[str] = field(default_factory=list)
     files: int = 0
     rules: list[str] = field(default_factory=list)
-    #: call-graph size when a package rule ran (bench.py emits these so
-    #: analysis-cost creep is visible round over round); 0 = not built.
+    #: call-graph size when a package rule ran (``p1 lint --json``
+    #: reports it); 0 = not built.
     callgraph_nodes: int = 0
     callgraph_edges: int = 0
     #: the --path scope of this run, empty = whole package.

@@ -554,8 +554,7 @@ class Chain:
         """Batch proof lookup: one ``TxProof`` (or None) per requested
         txid, sharing a single merkle-tree construction per distinct
         containing block via the proof cache.  The serving plane's
-        amortized API (benchmarks/query_plane.py measures it against
-        the serial per-proof baseline)."""
+        amortized API."""
         tip = self.height
         out: dict[bytes, TxProof | None] = {}
         for txid in txids:

@@ -20,9 +20,9 @@ makes chain length a *disk* problem for the serving path:
 - ``ArchiveChain`` is the serve-only composition: ledger state from a
   PR 9 snapshot (``Chain.from_snapshot`` — the bounded hot window of
   real ``_Entry`` headers), cold headers/proof lookups from the
-  on-disk plane below the base.  A synthetic 10M-block store boots to
+  on-disk plane below the base.  A synthetic 1M-block store boots to
   serving header/balance/proof queries under 1 GB peak RSS
-  (benchmarks/archive_scale.py measures VmHWM).
+  (``tests/test_headerplane.py`` measures VmHWM in a fresh process).
 
 Ordinal == height: the plane assumes a LINEAR store (compacted /
 synthetic / pruned-serve archives — main branch only, append order =
@@ -314,9 +314,7 @@ class HeaderPlane:
 class ArchiveChain:
     """Serve-only archive boot: a bounded hot ``Chain`` window anchored
     on a snapshot, backed by the header plane for everything below the
-    base.  RAM is O(hot window + accounts + touched pages); the 10M
-    synthetic store in benchmarks/archive_scale.py is the measured
-    proof.
+    base.  RAM is O(hot window + accounts + touched pages).
 
     Trust model: the snapshot passed chain/snapshot.py's integrity
     gates and the store is this host's own (or a verified copy); the

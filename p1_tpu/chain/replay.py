@@ -78,8 +78,7 @@ def pack_headers(headers: list[BlockHeader]) -> bytes:
     planes.  ``BlockHeader.serialize`` memoizes, so for headers a node
     already holds (ingested off the wire, or serialized once before)
     this is a join of cached buffers: no per-header struct packing, which
-    is what closes replay-from-objects toward the raw-bytes rate
-    (docs/PERF.md "host ingest plane")."""
+    is what closes replay-from-objects toward the raw-bytes rate."""
     try:
         # C-level gather of the memoized encodings (the cache slot is a
         # plain instance attribute) — the join is the whole cost.

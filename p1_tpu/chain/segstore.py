@@ -536,10 +536,10 @@ class SegmentedStore(ChainStore):
         block_hash: bytes | None = None,
     ) -> None:
         """Append one pre-serialized record (the bulk-ingest /
-        synthetic-archive path: benchmarks craft record bytes directly
-        and skip the object layer entirely).  ``block_hash`` registers
-        the body span when given; without it the record is simply not
-        refetchable until the next reindex."""
+        synthetic-archive path: ``tests/archiveutil.py`` crafts record
+        bytes directly and skips the object layer entirely).
+        ``block_hash`` registers the body span when given; without it the
+        record is simply not refetchable until the next reindex."""
         self.acquire()
         if len(raw) > _MAX_RECORD:
             raise ValueError(

@@ -36,8 +36,8 @@ Trust model (spelled out because it is easy to over-read): the state
 root proves the *chunks* match the *manifest* — nothing more.  Until
 background revalidation replays the real history and reproduces the
 same root at the same height, the whole snapshot — root included — is
-just the serving peer's claim.  ``docs/ROUND12.md`` carries the full
-honesty notes; ``node/node.py`` carries the ASSUMED→VALIDATED flip.
+just the serving peer's claim.  ``node/node.py`` carries the
+ASSUMED→VALIDATED flip.
 """
 
 from __future__ import annotations

@@ -121,12 +121,12 @@ class ChainStore:
 
     Durability contract: with ``fsync=True`` (the default) every
     ``append`` returns only after ``os.fsync`` — an acknowledged block
-    survives OS crash / power loss, not just process death.  At benchmark
-    block rates the cost is noise next to the PoW (measured ~1.9 ms/append
-    on this VM's fs vs ≥120 ms blocks; see docs/PERF.md).  ``fsync=False`` keeps only the
-    process-crash guarantee (the flush + torn-tail truncation story) for
-    workloads that prefer raw append throughput, e.g. bulk ``save_chain``
-    snapshots, which are re-derivable.
+    survives OS crash / power loss, not just process death.  At mining
+    block rates the cost is small next to the PoW (~1.9 ms/append on a
+    1-vCPU host against ≥120 ms blocks; not measured on the chip host).
+    ``fsync=False`` keeps only the process-crash guarantee (the flush +
+    torn-tail truncation story) for workloads that prefer raw append
+    throughput, e.g. bulk ``save_chain`` snapshots, which are re-derivable.
 
     The file layer is routed through four overridable seams
     (``_open_fh``/``_fsync_file``/``_fsync_dir``/``_read_bytes``) so the
@@ -340,7 +340,7 @@ class ChainStore:
             )
         # ``serialize`` is memoized on the block: for a block that arrived
         # off the wire these are the exact gossip bytes — ingest appends
-        # with zero re-packing (docs/PERF.md "host ingest plane").
+        # with zero re-packing.
         raw = block.serialize()
         if len(raw) > _MAX_RECORD:
             # The scan rejects bigger length fields as corruption, so a
@@ -515,7 +515,7 @@ class ChainStore:
         seeds the block's (and its header's and transactions') encoding
         caches with the record's exact bytes, so resume never re-packs —
         ``add_block``'s hashing, the ledger's txids, and any later relay
-        all reuse the disk bytes (docs/PERF.md "Restart at scale")."""
+        all reuse the disk bytes."""
         return list(self.iter_blocks())
 
     def iter_blocks(self):
@@ -530,8 +530,7 @@ class ChainStore:
         (pread against the page cache the scan just warmed), so the
         build phase — where the per-block index objects accumulate —
         never also carries an O(chain) byte buffer.  At 100k blocks that
-        is ~24 MB off the resume's peak RSS (docs/PERF.md
-        "Memory-bounded operation")."""
+        is ~24 MB off the resume's peak RSS."""
         if not self.path.exists():
             return
         data = self._read_checked()
@@ -661,8 +660,8 @@ class ChainStore:
         it was appended (and the store is exclusively flocked, so nothing
         else wrote it), so the stateless checks — Ed25519 signatures
         above all — are skipped while the contextual rules and the
-        connect-time ledger still rebuild identical state (measured ~3x
-        end-to-end at 100k blocks — 4.6 s vs 14.0 s, docs/PERF.md;
+        connect-time ledger still rebuild identical state (~3x faster at
+        100k blocks on a 1-vCPU host, not measured on the chip host;
         equivalence is tested).  The v3 record checksum bounds what
         trust costs: bit-rot inside a record body now fails the CRC and
         the record is quarantined at ``acquire`` rather than trusted
@@ -694,15 +693,13 @@ class ChainStore:
         parse (``load_blocks``) seeds every block's encoding caches from
         the record bytes, so the per-block hashing that ``add_block`` and
         the ledger need digests the disk bytes directly — no
-        re-serialization anywhere in the resume loop (measured in
-        benchmarks/host_ingest.py, recorded in docs/PERF.md).
+        re-serialization anywhere in the resume loop.
 
         ``body_cache=N`` (memory-bounded resume) wires the chain's body
         refetch to THIS store and streams the log through periodic body
         eviction, so peak RSS is bounded by the keep window instead of
         the whole chain's object graph — the governor's memory-bounded
-        operation starts at boot, not after it (docs/PERF.md
-        "Memory-bounded operation").
+        operation starts at boot, not after it.
 
         Untrusted loads (``trusted=False`` — `--revalidate-store`,
         foreign stores) run through the validation fast lane: blocks
@@ -712,8 +709,7 @@ class ChainStore:
         cache before ``add_block``'s per-block ``check_block`` consults
         it — one batch call per ~4k signatures instead of one backend
         call per transfer, with bit-identical accept/reject outcomes
-        (the warmer only ever caches proofs that hold; docs/PERF.md
-        "Untrusted-path validation")."""
+        (the warmer only ever caches proofs that hold)."""
         chain = Chain(difficulty, retarget=retarget)
         if body_cache > 0:
             chain.body_source = self

@@ -7,7 +7,6 @@ SURVEY.md §7 step 7 — every benchmark config reproducible from one command
   config 3:   p1 replay --n 10000 --difficulty 12
   config 4:   p1 net    --nodes 4 --difficulty 20 --duration 10
   one node:   p1 node   --port 9444 --peers host:port --mine
-  headline:   p1 bench
 
 (``p1`` = ``python -m p1_tpu``.)  Structured logs go to stderr; metric
 output is JSON on stdout, one object per line, so the driver and shell
@@ -906,8 +905,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="TEST ONLY: seed a known recovery bug so the shrink/repro "
         "pipeline can be exercised against a guaranteed violation",
     )
-
-    sub.add_parser("bench", help="headline benchmark (one JSON line)")
 
     p = sub.add_parser(
         "lint",
@@ -2370,21 +2367,6 @@ def cmd_lint(args) -> int:
     return 0 if report.clean else 1
 
 
-def cmd_bench(args) -> int:
-    # bench.py lives at the repo root (the driver contract), one level above
-    # the package — resolve it by path so `p1 bench` works from any cwd.
-    import importlib.util
-    from pathlib import Path
-
-    bench_path = Path(__file__).resolve().parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench", bench_path)
-    assert spec is not None and spec.loader is not None
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench.main()
-    return 0
-
-
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     logging.basicConfig(
@@ -2417,7 +2399,6 @@ def main(argv=None) -> int:
         "sim": cmd_sim,
         "chaos": cmd_chaos,
         "lint": cmd_lint,
-        "bench": cmd_bench,
     }[args.cmd]
     return handler(args)
 

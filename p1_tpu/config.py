@@ -95,9 +95,9 @@ class NodeConfig:
     #: block BODIES resident in the RAM index (headers and all metadata
     #: stay), evicting older bodies once they are durably refetchable
     #: from the append-only store and re-reading them on demand.  Cuts
-    #: steady-state and resume peak RSS from O(chain) to O(N)
-    #: (docs/PERF.md "Memory-bounded operation").  0 disables (fully
-    #: resident — the historical behavior); requires ``store_path``.
+    #: steady-state and resume peak RSS from O(chain) to O(N).  0
+    #: disables (fully resident — the historical behavior); requires
+    #: ``store_path``.
     body_cache_blocks: int = 0
     #: Segmented store layout (chain/segstore.py): shard the append-only
     #: log into bounded segment files of this many bytes (per-segment
@@ -190,8 +190,8 @@ class NodeConfig:
     #: every stored block at boot instead of the trusted fast resume.
     #: The store is this node's own flocked append-only log of blocks it
     #: already validated, so the default trusts it (~3x faster boots at
-    #: 100k blocks, docs/PERF.md); set True when on-disk integrity is in
-    #: question.
+    #: 100k blocks on a 1-vCPU host, not measured on the chip host); set
+    #: True when on-disk integrity is in question.
     revalidate_store: bool = False
     #: Version-bits protocol evolution (chain/versionbits.py, the BIP9
     #: analog, round 20): named feature deployments as

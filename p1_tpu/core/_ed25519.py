@@ -17,9 +17,7 @@ that affordable on the hot paths: the tx-level verify-once cache
 ``verify_batch`` below amortizes what DOES have to be verified —
 untrusted-path validation (`--revalidate-store`, foreign stores, deep
 sync) verifies whole windows of signatures in one multi-scalar
-multiplication instead of one double-and-add ladder each
-(benchmarks/sig_verify.py; see RECORDED_* below for the measured
-per-signature costs on the 1-vCPU bench host).
+multiplication instead of one double-and-add ladder each.
 
 Batch semantics, stated precisely: the batch accepts iff (a) every
 ``R_i`` and ``A_i`` point lies in the prime-order subgroup — checked
@@ -57,13 +55,6 @@ from __future__ import annotations
 import functools
 import hashlib
 import secrets
-
-#: Recorded fallback verify costs on the 1-vCPU bench host (2026-08-04,
-#: benchmarks/sig_verify.py) — what keys.py's one-time "fallback active
-#: for a batch path" warning names, so CI-without-wheel numbers are
-#: never mistaken for regressions against the wheel-based records.
-RECORDED_SERIAL_MS = 3.2
-RECORDED_BATCH_MS = 1.45
 
 _P = 2**255 - 19  # field prime
 _Q = 2**252 + 27742317777372353535851937790883648493  # group order

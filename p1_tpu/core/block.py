@@ -48,7 +48,7 @@ def merkle_levels(txids: list[bytes]) -> list[list[bytes]]:
     the levels held, EVERY transaction's branch is then O(log N) slice
     picks — amortizing the tree across all proofs for one block is what
     turns per-proof merkle reconstruction from the serving plane's
-    dominant cost into noise (benchmarks/query_plane.py).
+    dominant cost into noise.
     """
     if not txids:
         raise ValueError("no txids")

@@ -32,9 +32,9 @@ instances through ``__init__``, so derived headers start with *fresh,
 empty* caches and can never inherit a stale encoding).  ``deserialize``
 seeds the cache with the exact wire bytes, which is what makes the ingest
 pipeline zero-repack: a header that arrived off the wire or disk is never
-packed again for hashing, storing, or relay (docs/PERF.md "host ingest
-plane").  The encoding is canonical — fixed-width fields — so the seeded
-bytes are byte-identical to a recomputation (tested).
+packed again for hashing, storing, or relay.  The encoding is canonical —
+fixed-width fields — so the seeded bytes are byte-identical to a
+recomputation (tested).
 """
 
 from __future__ import annotations

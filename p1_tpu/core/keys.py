@@ -536,8 +536,6 @@ def _warn_fallback_once() -> None:
     if _fallback_warned:
         return
     _fallback_warned = True
-    from p1_tpu.hashx.perf_record import RECORDED_SIG_NATIVE_MS
-
     if _sig_backend == "pure-python":
         fastest = (
             "the pure-Python fallback was FORCED via "
@@ -549,19 +547,13 @@ def _warn_fallback_once() -> None:
         fastest = "the native C++ engine is available on this host"
     else:
         fastest = (
-            "fastest available here; the native C++ engine "
-            f"(~{RECORDED_SIG_NATIVE_MS:.2f} ms/sig batched, recorded) "
-            "needs only a C++ toolchain, and the `cryptography` wheel "
-            "(~0.1 ms/sig) neither"
+            "fastest available here; the native C++ engine needs only a "
+            "C++ toolchain, and the `cryptography` wheel neither"
         )
     log.warning(
         "pure-Python Ed25519 fallback is the active backend for batch "
-        "verification: ~%.1f ms/signature serial, ~%.2f ms batched "
-        "(recorded on the 1-vCPU bench host) — %s.  Numbers measured on "
-        "this rung are NOT comparable to the wheel- or native-based "
-        "records in docs/PERF.md.",
-        _py_ed25519.RECORDED_SERIAL_MS,
-        _py_ed25519.RECORDED_BATCH_MS,
+        "verification, the slowest rung — %s.  Numbers measured on this "
+        "rung are NOT comparable to the native engine's or the wheel's.",
         fastest,
     )
 

@@ -48,10 +48,9 @@ pinned by the torsion/corruption matrix in tests/test_ed25519_device.py
 — so ``core/keys.py`` can route batches here (``--sig-backend device``)
 with ``first_invalid``'s serial settlement unchanged.
 
-Honest scope note (docs/ROUND15.md): on a single CPU host the mesh is
-virtual and this path measures architecture cost, not speedup — the
-native C++ engine is the host fast lane.  The figure that matters here
-is the devices-vs-throughput scaling row in docs/PERF.md.
+Scope: on a single CPU host the mesh is virtual and this path measures
+architecture cost, not speedup — the native C++ engine is the host fast
+lane.  No chip cell measures this path yet (ROADMAP R9, D5).
 """
 
 from __future__ import annotations

@@ -45,8 +45,9 @@ _U32 = jnp.uint32
 #: step whose prior's ``found`` is set skips its work.
 StepFn = Callable[..., jax.Array]
 
-#: Default device-step batch by platform.  The TPU sweep peaked near 2**24
-#: per step (106 MH/s pipelined; 2**26 gains little and slows aborts); on
+#: Default device-step batch by platform.  On TPU, 2**24 was the peak of a
+#: sweep through the removed remote-device runtime; no chip cell runs the
+#: XLA backend on TPU, so it is not measured on the chip (ROADMAP D5).  On
 #: CPU the fori_loop carry holds ~25 live uint32 arrays per lane, so 2**24
 #: would mean a ~2 GB working set and minutes between abort checks on a
 #: 1-vCPU box — 2**18 keeps both in the tens-of-MB / sub-second range.
@@ -65,9 +66,10 @@ def is_tpu_platform(platform: str | None = None) -> bool:
 
 #: Opening-ramp parameters (see ``PipelinedSearchMixin.search``).  The floor
 #: is sized so a difficulty-20 hit (expected at ~2²⁰ nonces) lands in the
-#: first step with ~98% probability.  Whether a lower floor pays on a
-#: directly attached chip, where a dispatch costs far less than the
-#: remote-device runtime it was sized on, is an open question (PERF.md).
+#: first step with ~98% probability.  It was sized on the removed
+#: remote-device runtime, where a dispatch cost far more; whether a lower
+#: floor pays on a directly attached chip is not measured yet (ROADMAP
+#: S2 lever 5).
 _RAMP_FLOOR = 1 << 22
 _RAMP_FACTOR = 8
 #: Ramp only when a hit inside the floor span is plausible: at difficulty d

@@ -4,10 +4,10 @@ Capability parity: the host-side mining tier done the way a native
 reference would do it (SURVEY.md §2's rule — native components get C++
 equivalents).  One C call scans a whole nonce range with the midstate
 trick; on CPUs with the SHA-NI extension (runtime-dispatched inside the
-.so) this measures ~10x the hashlib loop (docs/PERF.md) from hardware
-rounds plus Python-overhead elimination.  Deterministic earliest-hit — same
-contract as every
-other backend, so it slots into the Miner/chain/node unchanged.
+.so) it ran ~10x the hashlib loop on a 1-vCPU host (not measured on the
+chip host) from hardware rounds plus Python-overhead elimination.
+Deterministic earliest-hit — same contract as every other backend, so it
+slots into the Miner/chain/node unchanged.
 """
 
 from __future__ import annotations

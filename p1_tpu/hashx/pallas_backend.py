@@ -56,17 +56,19 @@ _U32 = jnp.uint32
 _K_WORDS = np.asarray(K, dtype=np.uint32)
 _IV_WORDS = np.asarray(IV, dtype=np.uint32)
 
-#: Rows of 128 lanes per grid step.  The v5e sweep (docs/PERF.md) put
-#: sub=16 on top: 2048 nonces/step keeps the full compression window
-#: (~30 live tile-arrays ≈ 0.25 MB) in VMEM with the best Mosaic schedule;
+#: Rows of 128 lanes per grid step.  A sweep through the removed
+#: remote-device runtime put sub=16 on top: 2048 nonces/step keeps the
+#: full compression window (~30 live tile-arrays ≈ 0.25 MB) in VMEM;
 #: larger tiles spill, smaller ones starve the VPU of independent work.
+#: Not swept on a directly attached chip (ROADMAP S10), where the kernel
+#: reads 62.9% of its op-count roofline on ``solo-d28`` (PERF.md §5).
 _DEFAULT_SUB = 16
 
 #: Device-step batch.  Unlike the XLA backend, the kernel materializes
 #: nothing per nonce in HBM, so a big batch costs only abort granularity.
 #: 2²⁷ was chosen by a sweep through a remote-device runtime whose
 #: per-dispatch cost no longer applies; whether it is still right on a
-#: directly attached chip is an open question for the benchmark (PERF.md).
+#: directly attached chip is not measured yet (ROADMAP S10).
 _DEFAULT_BATCH = 1 << 27
 
 

@@ -79,8 +79,10 @@ def jit_sharded_step(
     ``kernel`` picks the per-device search body: ``"xla"`` (the jax_sha256
     formulation — also what CPU validation meshes run) or ``"pallas"``
     (the Mosaic kernel of pallas_backend inside shard_map, so every chip
-    of a real TPU mesh mines at the single-chip kernel rate, docs/PERF.md;
-    ``interpret=True`` runs it in the Pallas interpreter on a CPU mesh).
+    of a real TPU mesh runs the single-chip kernel; ``interpret=True``
+    runs it in the Pallas interpreter on a CPU mesh).  On four v5e chips
+    the kernel reads 33.8% of its op-count roofline against one chip's
+    62.9%, the rest lost to straggling in ``pmin`` (PERF.md §5).
     """
     n = mesh.devices.size
     span = n * batch_per_device
@@ -184,8 +186,8 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
             kernel = "pallas" if is_tpu_platform(mesh_platform) else "xla"
         if batch is None:
             if kernel == "pallas" and is_tpu_platform(mesh_platform):
-                # The kernel's rate comes from big dispatch-amortizing
-                # steps (docs/PERF.md), not the XLA-carry-sized default.
+                # The kernel's own big dispatch-amortizing step, not the
+                # XLA-carry-sized default.
                 from p1_tpu.hashx.pallas_backend import _DEFAULT_BATCH
 
                 batch = _DEFAULT_BATCH

@@ -192,8 +192,8 @@ void compress_shani(uint32_t state[8], const uint8_t block[64]) {
 // instruction latency gates throughput while issue slots idle.  Header
 // digests in a chain verify are mutually independent, so interleaving two
 // of them fills those slots and nearly doubles verified headers/s
-// (measured in benchmarks/host_ingest.py; parity-fuzzed against the
-// hashlib oracle like every other engine path).  The K constants load
+// (on a 1-vCPU host, not measured on the chip host; parity-fuzzed against
+// the hashlib oracle like every other engine path).  The K constants load
 // once per quad-round and feed both lanes.
 __attribute__((target("sha,sse4.1")))
 void compress_shani2(uint32_t sa[8], const uint8_t* ba, uint32_t sb[8],

@@ -37,7 +37,7 @@ hashes into the simulator's event-trace digest; two runs of one seed
 are byte-identical in-process and across processes under
 PYTHONHASHSEED (tests/test_chaos.py, tests/test_cli.py).
 
-What the crash model does NOT capture (honesty, docs/ROUND11.md): the
+What the crash model does NOT capture: the
 torn-append artifact is the FaultStore's single-record tear — kernel
 page-cache reordering that loses an EARLIER acknowledged write while a
 later one survives is outside it (the store fsyncs per append, so that

@@ -53,8 +53,7 @@ on an asyncio loop, so the blocking pipe reads need no
 transitive-blocking grant in p1_tpu/analysis/allowlist.py, and must
 not grow one by moving onto a loop.
 
-What the far-field model does NOT capture (honesty — docs/PERF.md
-"Sharded far field" repeats this next to the numbers): no transaction
+What the far-field model does NOT capture: no transaction
 traffic, mempools, ledgers, or stores (headers only); no bandwidth
 shaping, handshakes, supervision, or admission control (a far-field
 node never stalls, floods, or gets banned); relay is announce-forward

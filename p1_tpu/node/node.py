@@ -795,8 +795,8 @@ class Node:
         #: ``registry.span()`` (dict lookup + context-manager + span
         #: allocation per region) measurably taxes it — the stage spans
         #: below use ``_tel_clock`` stamps + cached histogram refs
-        #: instead (~half the cost; benchmarks/telemetry_overhead.py is
-        #: the receipt).  ``_tel_clock is None`` IS the disabled check.
+        #: instead (~half the cost on a 1-vCPU host).  ``_tel_clock is
+        #: None`` IS the disabled check.
         if self.telemetry.enabled:
             self._tel_clock = self.clock.monotonic
             self._h_frame = self.telemetry.histogram("stage.frame_s")
@@ -4750,9 +4750,9 @@ class Node:
         # exact frame bytes in its encoding cache (core/block.py), so the
         # hashing below (add_block's validation), the store append, and
         # the re-relay encode all reuse them — the block is packed at
-        # most once per process lifetime (docs/PERF.md "host ingest
-        # plane").  Only mempool-reconstructed compact blocks serialize
-        # fresh, once, on first use (their full frame never arrived).
+        # most once per process lifetime.  Only mempool-reconstructed
+        # compact blocks serialize fresh, once, on first use (their full
+        # frame never arrived).
         clk = self._tel_clock
         if height is None:
             twin = no_twin
@@ -5290,9 +5290,8 @@ class Node:
             "maintenance": self.maintenance_report(),
             # Query serving plane (round 9): read-traffic counters (how
             # many proofs/filters this node served and at what cache hit
-            # rate) — the host-side view of the tier benchmarks/
-            # query_plane.py measures; replica workers (`p1 serve`)
-            # report their own copy of this block over GETSTATUS.
+            # rate); replica workers (`p1 serve`) report their own copy
+            # of this block over GETSTATUS.
             "queries": {
                 "proofs_served": self.metrics.proofs_served,
                 "filters_served": self.metrics.filters_served,

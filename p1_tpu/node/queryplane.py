@@ -30,8 +30,7 @@ the consensus loop but the append-only store file itself:
   ``ProofCache`` (chain/proof.py — whole-block merkle amortization +
   serialized-payload memoization + 4-byte tip patches) and filters
   through a ``FilterIndex`` (chain/filters.py), so a replica's steady-
-  state QPS is dict lookups and byte splices, measured in
-  benchmarks/query_plane.py.
+  state QPS is dict lookups and byte splices.
 
 - **Governor admission.**  Every session gets a per-peer query budget
   (node/governor.py ``ResourceGovernor``) charged at the dispatch

@@ -1976,8 +1976,8 @@ def fleet_failover(
     one session failed over, every session's height stream is
     contiguous with every event matched (each block pays the watched
     wallet), and the mesh converged with the ledger conserved.  The
-    wall-clock fleet figure (notify p95 under kill, queue depth) is
-    ``benchmarks/wallet_plane.py``'s job — this scenario pins the
+    wall-clock fleet figure (notify p95 under kill, queue depth) waits
+    for a wallet-fleet cell (ROADMAP R4) — this scenario pins the
     CORRECTNESS half in virtual time, replayable by seed."""
     from p1_tpu.node import client
 

@@ -7,7 +7,7 @@ callables that abstract the session: ``send`` (enqueue one encoded
 frame), ``buffer_size`` (bytes queued on the transport), ``close``
 (disconnect).  The manager is deliberately transport-agnostic so the
 same code pushes over real sockets (Node, QueryPlaneServer), simulated
-transports (chaos), and in-process sinks (benchmarks) — the write
+transports (chaos), and in-process sinks (tests) — the write
 buffer IS the per-session queue, bounded by the same governor caps that
 bound every other session.
 

@@ -141,7 +141,7 @@ class Histogram:
         # An unboxed array, not a list of ints: observe() runs on the
         # node's per-frame hot path, and a boxed-int counts list costs
         # an int allocation per increment plus a cache line per touched
-        # box (benchmarks/telemetry_overhead.py is the receipt).
+        # box.
         self.counts = _array("Q", [0]) * len(bounds)
         self.overflow = 0  # samples above the last bound
         self.count = 0

@@ -1,43 +1,23 @@
-"""Archive-scale benchmark: a synthetic multi-million-block SEGMENTED
-store, and what it costs to build, resume, boot, and query (round 18 —
-ROADMAP item 5's acceptance shape).
+"""A synthetic multi-million-block SEGMENTED archive, and its fresh-process
+boot, for ``test_headerplane.py``'s archive-scale acceptance test.
 
 The store is coinbase-only and LINEAR (genesis at record 0, ordinal ==
 height — the compacted/archive shape), crafted at the BYTE level: one
 template coinbase transaction whose seq field is patched per height,
 headers packed directly, records appended through
-``SegmentedStore.append_raw`` — no Block objects anywhere in the build
-loop, so generation runs at hashing speed and the 10M build is minutes,
-not hours.  The first blocks are cross-checked byte-identical against
-the real object serializer, so the synthetic store is exactly what a
-node would have written.
+``SegmentedStore.append_raw`` — no Block objects in the build loop.  The
+first blocks are cross-checked byte-identical against the real object
+serializer, so the synthetic store is exactly what a node would have
+written.
 
-Phases, each its own figure:
-
-- **ingest** (``archive_ingest_bps``) — crafted records/s through the
-  segmented append plane (CRC framing, rolls, hdrx seals; fsync off —
-  the bulk-build shape);
-- **resume** (``archive_resume_bps``) — whole-archive packed-header
-  extraction (``SegmentedStore.packed_headers``): the scan-everything
-  rate a header-plane rebuild or full PoW replay pays;
-- **boot** (``archive_boot_s`` / ``archive_boot_rss_mb``) — a FRESH
-  subprocess opens ``ArchiveChain`` (snapshot ledger + mmap'd header
-  plane + bounded tail replay) and serves header/balance/proof
-  queries; peak RSS is VmHWM from /proc, the fork-proof number.  The
-  acceptance bar: 10M blocks under 1 GB.
-- **query** (``archive_query_qps``) — random-height header queries
-  against the booted archive (mmap page touches, no object builds).
-
-Default is a 100k-block store (tier-1-adjacent wall time).  The full
-ladder the PERF table records (100k / 1M / 10M) runs via ``--blocks``;
-bench.py runs the 10M shape only under ``P1_BENCH_ARCHIVE=1``.
+The boot runs in a FRESH process (this file, ``--phase boot``) so that
+its VmHWM is the archive's serving footprint, not the build's.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import struct
 import subprocess
 import sys
@@ -53,6 +33,10 @@ _MINER = "bench-miner"
 #: Snapshot cadence for the synthetic archive: the boot's tail replay
 #: is bounded by one interval, so keep it small relative to the store.
 SNAP_INTERVAL = 4096
+DIFFICULTY = 1
+SEGMENT_BYTES = 16 << 20
+#: Random-height header queries the boot serves (and up to 100 proofs).
+QUERIES = 2000
 
 
 def _tx_template(miner: str) -> tuple[bytearray, int]:
@@ -69,13 +53,7 @@ def _tx_template(miner: str) -> tuple[bytearray, int]:
     return bytearray(a), seq_off
 
 
-def build_archive(
-    store_path: Path,
-    n_blocks: int,
-    segment_bytes: int,
-    difficulty: int = 1,
-    snap_interval: int = SNAP_INTERVAL,
-) -> dict:
+def build_archive(store_path: Path, n_blocks: int) -> dict:
     """Craft the linear store + its snapshot sidecar; returns timings."""
     from hashlib import sha256
 
@@ -89,13 +67,13 @@ def build_archive(
     def sha256d(b: bytes) -> bytes:
         return sha256(sha256(b).digest()).digest()
 
-    genesis = make_genesis(difficulty)
+    genesis = make_genesis(DIFFICULTY)
     tx, seq_off = _tx_template(_MINER)
     pack_seq = struct.Struct(">Q").pack_into
-    base = (max(n_blocks - 1, 1) // snap_interval) * snap_interval
+    base = (max(n_blocks - 1, 1) // SNAP_INTERVAL) * SNAP_INTERVAL
     anchor_payload: bytes | None = None
     store = SegmentedStore(
-        store_path, fsync=False, segment_bytes=segment_bytes
+        store_path, fsync=False, segment_bytes=SEGMENT_BYTES
     )
     t0 = time.perf_counter()
     store.append_raw(genesis.serialize(), height=0)
@@ -105,13 +83,13 @@ def build_archive(
     for h in range(1, n_blocks):
         pack_seq(tx, seq_off, h)
         txid = sha256d(tx)
-        hdr = _HDR.pack(1, prev, txid, ts0 + h, difficulty, 0)
+        hdr = _HDR.pack(1, prev, txid, ts0 + h, DIFFICULTY, 0)
         payload = hdr + tx_len_prefix + bytes(tx)
         if h <= 3:
             # Self-check: crafted bytes are EXACTLY what the object
             # layer serializes — the synthetic store is real.
             real = Block(
-                header=BlockHeader(1, prev, txid, ts0 + h, difficulty, 0),
+                header=BlockHeader(1, prev, txid, ts0 + h, DIFFICULTY, 0),
                 txs=[Transaction.coinbase(_MINER, h)],
             ).serialize()
             assert payload == real, "crafted record diverged from objects"
@@ -120,7 +98,7 @@ def build_archive(
             anchor_payload = payload
         prev = sha256d(hdr)
         if h % 262144 == 0:
-            # The span dict is the only O(chain) term in the builder;
+            # The span dict is the only O(chain) term in the build loop;
             # the archive boot reindexes lazily from disk anyway.
             store._body_spans.clear()
     store.sync()
@@ -141,10 +119,6 @@ def build_archive(
         "archive_ingest_bps": round((n_blocks - 1) / build_s),
         "segments": segments,
         "snapshot_base": base,
-        "store_bytes": sum(
-            f.stat().st_size
-            for f in store_path.with_name(store_path.name + ".d").iterdir()
-        ),
     }
 
 
@@ -165,7 +139,7 @@ def measure_resume(store_path: Path) -> dict:
     }
 
 
-def boot_phase(store_path: str, difficulty: int, queries: int) -> None:
+def boot_phase(store_path: str) -> None:
     """Subprocess body: boot the archive, serve queries, report VmHWM."""
     import random
 
@@ -173,13 +147,13 @@ def boot_phase(store_path: str, difficulty: int, queries: int) -> None:
 
     snap = store_path + ".archsnap"
     t0 = time.perf_counter()
-    arch = ArchiveChain(store_path, snap, difficulty)
+    arch = ArchiveChain(store_path, snap, DIFFICULTY)
     boot_s = time.perf_counter() - t0
     rng = random.Random(18)
     height = arch.height
     # Header queries: random heights across the WHOLE archive.
     t0 = time.perf_counter()
-    for _ in range(queries):
+    for _ in range(QUERIES):
         h = rng.randrange(0, height + 1)
         assert arch.header_bytes_at(h) is not None
     query_s = time.perf_counter() - t0
@@ -190,7 +164,7 @@ def boot_phase(store_path: str, difficulty: int, queries: int) -> None:
 
     proofs = 0
     t0 = time.perf_counter()
-    for _ in range(min(100, queries)):
+    for _ in range(min(100, QUERIES)):
         h = rng.randrange(1, height + 1)
         struct.Struct(">Q").pack_into(tx, seq_off, h)
         txid = sha256(sha256(bytes(tx)).digest()).digest()
@@ -209,7 +183,7 @@ def boot_phase(store_path: str, difficulty: int, queries: int) -> None:
             {
                 "archive_boot_s": round(boot_s, 4),
                 "archive_boot_rss_mb": round(vmhwm_kb / 1024.0, 1),
-                "archive_query_qps": round(queries / query_s),
+                "archive_query_qps": round(QUERIES / query_s),
                 "archive_proof_qps": round(proofs / proof_s),
                 "height": height,
             }
@@ -217,22 +191,11 @@ def boot_phase(store_path: str, difficulty: int, queries: int) -> None:
     )
 
 
-def measure_boot(store_path: Path, difficulty: int, queries: int) -> dict:
+def measure_boot(store_path: Path) -> dict:
     """Run the boot phase in a FRESH process so VmHWM is the archive
-    serving footprint, not this builder's."""
+    serving footprint, not this build process's."""
     proc = subprocess.run(
-        [
-            sys.executable,
-            __file__,
-            "--phase",
-            "boot",
-            "--store",
-            str(store_path),
-            "--difficulty",
-            str(difficulty),
-            "--queries",
-            str(queries),
-        ],
+        [sys.executable, __file__, "--phase", "boot", "--store", str(store_path)],
         capture_output=True,
         text=True,
         timeout=1800,
@@ -242,67 +205,20 @@ def measure_boot(store_path: Path, difficulty: int, queries: int) -> dict:
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def bench_archive(
-    n_blocks: int,
-    segment_bytes: int = 16 << 20,
-    difficulty: int = 1,
-    queries: int = 2000,
-    keep: str | None = None,
-) -> dict:
-    import tempfile
-
-    ctx = (
-        tempfile.TemporaryDirectory(prefix="p1archive")
-        if keep is None
-        else None
-    )
-    tmp = Path(ctx.name) if ctx is not None else Path(keep)
-    tmp.mkdir(parents=True, exist_ok=True)
-    try:
-        store_path = tmp / "archive.dat"
-        out = {"blocks": n_blocks, "segment_bytes": segment_bytes}
-        if not store_path.exists():
-            out.update(build_archive(store_path, n_blocks, segment_bytes))
-        out.update(measure_resume(store_path))
-        out.update(measure_boot(store_path, difficulty, queries))
-        return out
-    finally:
-        if ctx is not None:
-            ctx.cleanup()
-
-
-def bench_quick(blocks: int = 100_000) -> dict:
-    """The bench.py probe: the 100k shape (seconds of wall time), same
-    code path as the 10M acceptance run."""
-    return bench_archive(blocks)
-
-
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--blocks", type=int, default=100_000)
-    ap.add_argument("--segment-mb", type=int, default=16)
-    ap.add_argument("--difficulty", type=int, default=1)
-    ap.add_argument("--queries", type=int, default=2000)
-    ap.add_argument(
-        "--keep", default=None, help="build/reuse the store in this dir"
-    )
-    ap.add_argument(
-        "--phase", choices=("all", "boot"), default="all"
-    )
-    ap.add_argument("--store", default=None, help="(boot phase) store path")
-    args = ap.parse_args()
-    if args.phase == "boot":
-        boot_phase(args.store, args.difficulty, args.queries)
-        return
-    out = bench_archive(
-        args.blocks,
-        segment_bytes=args.segment_mb << 20,
-        difficulty=args.difficulty,
-        queries=args.queries,
-        keep=args.keep,
-    )
-    print(json.dumps(out))
+def bench_archive(n_blocks: int, workdir: Path) -> dict:
+    """Build an ``n_blocks`` archive under ``workdir``, then measure its
+    resume scan and its fresh-process boot."""
+    workdir.mkdir(parents=True, exist_ok=True)
+    store_path = workdir / "archive.dat"
+    out = {"blocks": n_blocks}
+    out.update(build_archive(store_path, n_blocks))
+    out.update(measure_resume(store_path))
+    out.update(measure_boot(store_path))
+    return out
 
 
 if __name__ == "__main__":
-    main()
+    ap = argparse.ArgumentParser(description="boot a built archive")
+    ap.add_argument("--phase", choices=("boot",), required=True)
+    ap.add_argument("--store", required=True)
+    boot_phase(ap.parse_args().store)

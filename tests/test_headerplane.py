@@ -204,12 +204,10 @@ class TestArchiveChain:
 def test_archive_scale_acceptance_1m(tmp_path):
     """The acceptance property at tier-1-adjacent scale: a synthetic
     1M-block segmented archive boots in a FRESH process and serves
-    header/balance/proof queries with peak RSS far under the 1 GB bar
-    (the 10M shape runs in bench.py behind P1_BENCH_ARCHIVE — same
-    code path, same flat-RSS mechanism)."""
-    from benchmarks.archive_scale import bench_archive
+    header/balance/proof queries with peak RSS far under the 1 GB bar."""
+    from archiveutil import bench_archive
 
-    out = bench_archive(1_000_000, keep=str(tmp_path / "arch"))
+    out = bench_archive(1_000_000, tmp_path / "arch")
     assert out["height"] == 999_999
     assert out["archive_boot_rss_mb"] < 1024, out
     assert out["archive_query_qps"] > 1_000

@@ -872,39 +872,3 @@ class TestMaintainWire:
                 await node.stop()
 
         run(scenario())
-
-
-class TestCadenceBench:
-    """The bench.py maintenance probe (benchmarks/maintenance_cadence.py)
-    against its perf_record.py pins: the metric names bench.py wires in
-    must exist, and the O(delta) claim must actually show up as a >1
-    incremental-over-full speedup even at a toy shape."""
-
-    def test_quick_probe_keys_and_speedup(self):
-        from benchmarks.maintenance_cadence import bench_quick
-
-        out = bench_quick(accounts=2_000, delta=16, blocks=48)
-        for key in (
-            "snapshot_incr_builds_per_sec",
-            "snapshot_full_builds_per_sec",
-            "snapshot_cadence_speedup",
-            "snapshot_chunks_reused",
-            "rebase_ms",
-            "rebase_dropped_blocks",
-            "rebase_freed_bytes",
-        ):
-            assert key in out, key
-        assert out["snapshot_cadence_speedup"] > 1.0
-        assert out["rebase_dropped_blocks"] > 0
-        assert out["rebase_ms"] < 1_000.0
-
-    def test_pins_exist_and_are_sane(self):
-        # The guard constants bench.py divides by: nonzero, right side
-        # of the degraded comparison (fraction < 1 for rates, factor > 1
-        # for latencies).
-        from p1_tpu.hashx import perf_record as pr
-
-        assert pr.RECORDED_SNAPSHOT_CADENCE_BPS > 0
-        assert pr.RECORDED_REBASE_MS > 0
-        assert 0 < pr.SNAPSHOT_CADENCE_DEGRADED_FRACTION < 1
-        assert pr.REBASE_DEGRADED_FACTOR > 1

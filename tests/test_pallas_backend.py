@@ -4,7 +4,7 @@ The compiled Mosaic path needs real TPU hardware; these tests run the
 IDENTICAL kernel through the Pallas interpreter on the CPU test platform
 (asked for with ``interpret=True``), so every lane of the round math, the
 target compare, and the first-hit min-reduction is checked without a chip.
-Throughput of the compiled kernel is bench.py's job.
+Throughput of the compiled kernel is measured on the chip by ``perfbench``.
 """
 
 import random

@@ -320,7 +320,7 @@ class TestVersionActivation:
 
 class TestRelayBudget:
     """Round 23: the flood-vs-reconciliation A/B over shaped uplinks.
-    Tier-1 runs the 10-node quick shape (the same one bench.py pins);
+    Tier-1 runs the 10-node quick shape;
     the slow set carries the 16-node acceptance run with its ≥5x
     budget at full storm scale."""
 

@@ -527,17 +527,14 @@ class TestMetricsWire:
         assert 0.0 < prop["p95"] < 1.0
 
     def test_replica_answers_getmetrics(self, tmp_path):
-        from benchmarks.host_ingest import build_blocks
-
         from p1_tpu.chain.store import ChainStore
-        from p1_tpu.core.block import Block
         from p1_tpu.node.queryplane import QueryPlaneServer, ReplicaView
+        from p1_tpu.node.testing import make_blocks
 
-        _chain, raws = build_blocks(4, 0, 1)
         store = ChainStore(tmp_path / "r.chain", fsync=False)
         try:
-            for raw in raws:
-                store.append(Block.deserialize(raw))
+            for block in make_blocks(4, difficulty=1)[1:]:
+                store.append(block)
         finally:
             store.close()
         view = ReplicaView(tmp_path / "r.chain", 1)
