@@ -82,7 +82,7 @@ REAL = Sizes(
     sigs=256,
     pod_difficulty=28,
     pod_range=1 << 30,
-    pod_batch=None,  # 2**27 per chip
+    pod_batch=None,  # sized from the difficulty (sharded.mesh_batch)
     pod_low_batch=2048,
     pod_low_difficulty=11,
     timeout_s=600.0,
@@ -452,7 +452,8 @@ def phase_pod(sizes: Sizes, seed: int) -> dict:
         (got.nonce, got.hashes_done) == (want.nonce, want.hashes_done),
         f"d{d}: mesh found {got.nonce}, one chip found {want.nonce}",
     )
-    batch, span = sharded.batch, sharded.step_span
+    batch = sharded.batch_for(d)
+    span = 4 * batch
     share = [0] * 4  # nonces of [0, hit] each chip was given
     for k in range(4):
         full, rest = divmod(want.nonce + 1, span)

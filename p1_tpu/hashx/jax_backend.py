@@ -110,7 +110,8 @@ def _no_span(name: str, **metadata):
 class PipelinedSearchMixin:
     """The host loop shared by every device-stepped backend.
 
-    Subclasses provide ``step_span`` (nonces evaluated per full device step)
+    Subclasses provide ``step_span`` (nonces evaluated per full device step;
+    the largest, where ``_step_span_for`` sizes it from the difficulty)
     and ``_make_step(span)`` (a jitted step function for a given span).
     ``search`` then scans an arbitrary range with a one-step pipeline and
     host-side masking of the partial final step.
@@ -139,8 +140,8 @@ class PipelinedSearchMixin:
     """
 
     step_span: int
-    #: Smallest opening step; None disables the ramp (sharded backend: the
-    #: per-device batch is baked into the mesh program).
+    #: Smallest opening step; None disables the ramp (sharded backend: it
+    #: sizes its mesh step from the difficulty instead).
     ramp_floor: int | None = _RAMP_FLOOR
     #: The JAX platform the steps run on; None for the default backend.
     platform: str | None = None
@@ -148,6 +149,12 @@ class PipelinedSearchMixin:
     def _make_step(self, span: int) -> StepFn:
         """A jitted step of ``span`` nonces, which takes the chained form."""
         raise NotImplementedError
+
+    def _step_span_for(self, difficulty: int) -> int:
+        """The full step of a search at ``difficulty``: ``step_span``,
+        unless the backend sizes its step from the difficulty (the
+        sharded backend does)."""
+        return self.step_span
 
     def _placement(self):
         """Where the steps run: a device or sharding for ``device_put``,
@@ -187,13 +194,14 @@ class PipelinedSearchMixin:
             midstate, tail, target = self._search_arrays(header_prefix, difficulty)
         steps.prepare_s = time.perf_counter() - t
 
+        full = self._step_span_for(difficulty)
         ramping = (
             self.ramp_floor is not None
             and nonce_start == 0
             and difficulty <= _RAMP_MAX_DIFFICULTY
-            and self.step_span > self.ramp_floor
+            and full > self.ramp_floor
         )
-        span = self.ramp_floor if ramping else self.step_span
+        span = self.ramp_floor if ramping else full
 
         # Batched scan with a one-step pipeline.  Each step covers
         # [base, base+span); a partial final step is masked on the host
@@ -218,7 +226,7 @@ class PipelinedSearchMixin:
             done += valid
             if done < count:
                 prior = pending[-1].prior()
-            span = min(span * _RAMP_FACTOR, self.step_span)
+            span = min(span * _RAMP_FACTOR, full)
             if len(pending) > 1:
                 hit = self._drain_one(pending, steps, span_of)
         while hit is None and pending:

@@ -15,6 +15,14 @@ rather than interleaved strides keep the global offset a pure affine map of
 the local one, so the ``pmin`` argument *is* the earliest-nonce order and
 the result is bit-identical to a single-device scan of the same range —
 the mesh-parity tests assert exactly that.
+
+The mesh step follows the difficulty.  A chip with no hit scans its whole
+block, so a step lasts a full per-chip scan whenever any chip misses, and
+every nonce past the first hit counts for nothing.  A fixed 2^27 per chip
+made a four-chip step 2^29 nonces against ~2^28 a d28 block, and the pod
+got 1.69 chips' worth out of four (PERF.md §6).  Unless the caller fixes
+the per-chip batch, ``mesh_batch`` sizes it per search so that a step is
+at most 2^(d - MESH_STEP_SHIFT) nonces, within the kernel's own bounds.
 """
 
 from __future__ import annotations
@@ -29,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from p1_tpu.hashx.backend import HashBackend, register
 from p1_tpu.hashx.jax_backend import (
+    _RAMP_FLOOR,
     PipelinedSearchMixin,
     StepFn,
     default_batch,
@@ -38,6 +47,27 @@ from p1_tpu.hashx.jax_sha256 import default_unroll, search_step
 
 _U32 = jnp.uint32
 AXIS = "chips"
+
+#: A mesh step at difficulty d covers at most 2^(d - MESH_STEP_SHIFT)
+#: nonces, so a block of ~2^d hashes takes ~2^MESH_STEP_SHIFT steps and
+#: overscans by at most one step.  Measured on four v5e chips at d28
+#: (PERF.md §6): a shift of 3 gained 1.3% over 2 (2.643 G against 2.608 G
+#: H/s) for 9.5 launches a block against 5.5, and each launch leaves the
+#: device ~1 ms idle, so 2 keeps the host's headroom.
+MESH_STEP_SHIFT = 2
+#: The smallest per-device batch off a TPU mesh: a multiple of the Pallas
+#: kernel's (16, 128) tile, and small enough that a CPU validation mesh
+#: sizes its step from the difficulty at the difficulties tests mine.
+_HOST_MESH_FLOOR = 1 << 12
+
+
+def mesh_batch(difficulty: int, n_devices: int, floor: int, cap: int) -> int:
+    """The per-device batch of a mesh step at ``difficulty``: the largest
+    power of two ``b`` with ``n_devices * b <= 2**(difficulty -
+    MESH_STEP_SHIFT)``, clamped to ``[floor, cap]``.  A pure function of
+    its arguments, so every process of a multi-host pod picks the same."""
+    shift = difficulty - MESH_STEP_SHIFT - (n_devices - 1).bit_length()
+    return min(max(1 << shift if shift >= 0 else 0, floor), cap)
 
 
 def make_mesh(
@@ -80,9 +110,8 @@ def jit_sharded_step(
     formulation — also what CPU validation meshes run) or ``"pallas"``
     (the Mosaic kernel of pallas_backend inside shard_map, so every chip
     of a real TPU mesh runs the single-chip kernel; ``interpret=True``
-    runs it in the Pallas interpreter on a CPU mesh).  On four v5e chips
-    the kernel reads 33.8% of its op-count roofline against one chip's
-    62.9%, the rest lost to straggling in ``pmin`` (PERF.md §5).
+    runs it in the Pallas interpreter on a CPU mesh).  Cached per batch:
+    the backend's difficulty-sized steps each compile once.
     """
     n = mesh.devices.size
     span = n * batch_per_device
@@ -161,10 +190,13 @@ def jit_sharded_step(
 class ShardedBackend(PipelinedSearchMixin, HashBackend):
     """SHA-256d search sharded over every chip of a device mesh.
 
-    ``batch`` is the per-device batch; one step evaluates
-    ``n_devices * batch`` nonces.  With one device this degrades gracefully
-    to the single-chip search (the ``pmin`` is a no-op), so the same backend
-    name works from a laptop CPU to a pod slice.
+    ``batch`` fixes the per-device batch; one step then evaluates
+    ``n_devices * batch`` nonces.  Left out, each search sizes it from its
+    difficulty (``batch_for``, ``mesh_batch``) between a floor and the
+    platform's default, which ``batch`` and ``step_span`` then hold: the
+    largest step.  With one device this degrades gracefully to the
+    single-chip search (the ``pmin`` is a no-op), so the same backend name
+    works from a laptop CPU to a pod slice.
     """
 
     def __init__(
@@ -184,6 +216,9 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
             # fallback: the Pallas body on a CPU mesh is asked for
             # explicitly (kernel="pallas", interpret=True).
             kernel = "pallas" if is_tpu_platform(mesh_platform) else "xla"
+        #: The smallest per-device batch ``batch_for`` picks; None when
+        #: the caller fixed the batch.
+        self.batch_floor: int | None = None
         if batch is None:
             if kernel == "pallas" and is_tpu_platform(mesh_platform):
                 # The kernel's own big dispatch-amortizing step, not the
@@ -193,6 +228,9 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
                 batch = _DEFAULT_BATCH
             else:
                 batch = default_batch(mesh_platform)
+            self.batch_floor = (
+                _RAMP_FLOOR if is_tpu_platform(mesh_platform) else _HOST_MESH_FLOOR
+            )
         if batch <= 0 or batch & (batch - 1):
             raise ValueError(f"batch must be a power of two, got {batch}")
         if kernel == "pallas":
@@ -223,14 +261,22 @@ class ShardedBackend(PipelinedSearchMixin, HashBackend):
             )
         self.unroll = unroll
         self.interpret = interpret
-        # No opening ramp: the per-device batch is baked into the mesh
-        # program, and a v5e-8 step is already granular enough per chip.
+        # No opening ramp: the step is sized from the difficulty instead.
         self.ramp_floor = None
 
+    def batch_for(self, difficulty: int) -> int:
+        """The per-device batch of a search at ``difficulty``."""
+        if self.batch_floor is None:
+            return self.batch
+        return mesh_batch(difficulty, self.n_devices, self.batch_floor, self.batch)
+
+    def _step_span_for(self, difficulty: int) -> int:
+        return self.n_devices * self.batch_for(difficulty)
+
     def _make_step(self, span: int) -> StepFn:
-        assert span == self.step_span, "sharded step span is fixed"
         return jit_sharded_step(
-            self.mesh, self.batch, self.unroll, self.kernel, self.interpret
+            self.mesh, span // self.n_devices, self.unroll, self.kernel,
+            self.interpret,
         )
 
     def _placement(self):

@@ -229,3 +229,96 @@ class TestPallasInMesh:
             get_backend("sharded", batch=1024, n_devices=1, kernel="pallas")
         with pytest.raises(ValueError, match="2\\*\\*31"):
             get_backend("sharded", batch=1 << 31, n_devices=1, kernel="pallas")
+
+
+class TestMeshStepRule:
+    """The mesh step follows the difficulty and the chip count unless the
+    caller fixes the per-chip batch."""
+
+    K = sharded.MESH_STEP_SHIFT
+
+    @pytest.mark.parametrize(
+        ("difficulty", "n_devices", "want"),
+        [
+            (28, 4, 1 << (26 - K)),  # pod-d28: a step of 2**(28-k)
+            (28, 8, 1 << (25 - K)),  # the source's v5e-8
+            (28, 3, 1 << (26 - K)),  # n*b <= 2**(d-k) rounds a chip count up
+            (28, 1, 1 << (28 - K)),
+            (32, 4, 1 << 27),  # high difficulty: today's step, the cap
+            (20, 4, 1 << 22),  # low difficulty: the floor
+            (0, 8, 1 << 22),
+        ],
+    )
+    def test_rule_on_a_tpu_mesh(self, difficulty, n_devices, want):
+        from p1_tpu.hashx.jax_backend import _RAMP_FLOOR
+        from p1_tpu.hashx.pallas_backend import _DEFAULT_BATCH, _DEFAULT_SUB
+
+        got = sharded.mesh_batch(difficulty, n_devices, _RAMP_FLOOR, _DEFAULT_BATCH)
+        assert got == want
+        assert got % (_DEFAULT_SUB * 128) == 0
+
+    @pytest.mark.parametrize("difficulty", [8, 20, 28, 32])
+    def test_explicit_batch_is_fixed(self, difficulty):
+        backend = get_backend("sharded", batch=256, n_devices=4)
+        assert backend.batch_floor is None
+        assert backend.batch_for(difficulty) == 256
+        assert backend._step_span_for(difficulty) == backend.step_span == 4 * 256
+
+    def test_step_span_and_chunk_keep_the_largest_step(self):
+        from p1_tpu.hashx.jax_backend import default_batch
+        from p1_tpu.miner import Miner
+
+        backend = get_backend("sharded", n_devices=4)
+        assert backend.batch == default_batch("cpu")
+        assert backend.step_span == 4 * default_batch("cpu")
+        assert Miner(backend=backend).chunk == 4 * backend.step_span
+        # Floor 2**12 a chip on a CPU mesh, cap the platform's default.
+        steps = {d: backend._step_span_for(d) for d in (0, 16 + self.K, 40)}
+        assert steps == {0: 4 << 12, 16 + self.K: 4 << 14, 40: backend.step_span}
+
+    def test_first_hit_in_a_later_adaptive_step_off_chip_0(self):
+        # At d18 on four CPU devices a step is 4 x 2**(16-k): find a header
+        # whose first hit lies in the second or third step, past chip 0's
+        # block, and check the mesh against the host's one-device scan.
+        difficulty = 18
+        backend = get_backend("sharded", n_devices=4)
+        batch = backend.batch_for(difficulty)
+        assert batch == 1 << (16 - self.K) > backend.batch_floor
+        step = 4 * batch
+        cpu = get_backend("cpu")
+        for seed in range(200, 400):
+            prefix = _prefix(seed)
+            truth = cpu.search(prefix, 0, 3 * step, difficulty)
+            if (
+                truth.nonce is not None
+                and truth.nonce >= step
+                and truth.nonce % step >= batch
+            ):
+                break
+        else:
+            pytest.fail("no seed with its first hit in step 2 or 3, past chip 0")
+        got = backend.search(prefix, 0, 4 * step, difficulty)
+        assert (got.nonce, got.hashes_done) == (truth.nonce, truth.hashes_done)
+        s = got.steps
+        hit_step = truth.nonce // step
+        assert s.launches == hit_step + 2  # the step in flight behind the hit
+        assert s.nonces_launched == s.launches * step
+        assert (s.launches_abandoned, s.nonces_skipped) == (1, step)
+
+    def test_pod_miner_agrees_and_mines_on_the_sized_step(self):
+        # One process: the handshake over (chunk, step_span) passes, and
+        # the lockstep miner seals at the first hit with the sized step.
+        from p1_tpu.core import meets_target
+        from p1_tpu.parallel.pod import PodMiner
+
+        backend = get_backend("sharded", n_devices=4)
+        miner = PodMiner(is_leader=True, backend=backend)
+        assert miner.chunk == 4 * backend.step_span
+        header = BlockHeader(1, bytes(32), bytes(32), 1735689700, 12, 0)
+        sealed = miner.search_nonce(header)
+        truth = get_backend("cpu").search(header.mining_prefix(), 0, 1 << 20, 12)
+        assert sealed.nonce == truth.nonce
+        assert meets_target(sealed.block_hash(), 12)
+        assert miner.last_stats.nonces_launched == (
+            miner.last_stats.launches * backend._step_span_for(12)
+        )

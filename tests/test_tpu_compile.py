@@ -105,6 +105,27 @@ def test_chained_search_step(topo, chips):
     assert ("all-reduce" in text) == (chips == 4)
 
 
+@pytest.mark.parametrize("difficulty", [20, 28])
+def test_sharded_step_sized_from_difficulty(topo, difficulty):
+    """The mesh steps a four-chip backend sizes from the difficulty (the
+    floor at d20; pod-d28's step), in the chained form the host loop
+    launches."""
+    from jax.sharding import Mesh
+    import numpy as np
+
+    from p1_tpu.hashx.jax_backend import _RAMP_FLOOR
+    from p1_tpu.hashx.pallas_backend import _DEFAULT_BATCH
+    from p1_tpu.hashx.sharded import AXIS, jit_sharded_step, mesh_batch
+
+    batch = mesh_batch(difficulty, 4, _RAMP_FLOOR, _DEFAULT_BATCH)
+    mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
+    sharding = NamedSharding(mesh, P())
+    args = _shapes(sharding, *SEARCH_ARGS, (2,))
+    text = jit_sharded_step(mesh, batch, kernel="pallas").lower(*args).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "all-reduce" in text
+
+
 def test_replay_scan_config3(topo):
     from p1_tpu.hashx.jax_sha256 import default_unroll, verify_header_chain_segments
 
