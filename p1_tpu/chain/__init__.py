@@ -11,6 +11,7 @@ from p1_tpu.chain.proof import (
 from p1_tpu.chain.replay import (
     ReplayReport,
     generate_headers,
+    pack_header_rows,
     pack_headers,
     parse_headers,
     replay_device,
@@ -66,6 +67,7 @@ __all__ = [
     "balances",
     "check_block",
     "generate_headers",
+    "pack_header_rows",
     "pack_headers",
     "parse_headers",
     "replay_device",

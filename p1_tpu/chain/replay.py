@@ -1,9 +1,10 @@
 """Chain replay: generate and verify long header chains (benchmark config 3).
 
 Capability parity: "chain replay: verify 10k-block header chain (hash-only,
-no mining)" (BASELINE.json:9).  TPU-first: the host packs the whole chain
-into one buffer and uploads it as it is (only a partial last segment is
-padded on the host); the device byte-swaps and joins it into
+no mining)" (BASELINE.json:9).  TPU-first: the host gathers the header
+objects' encodings straight into one NumPy array (``pack_header_rows``)
+and uploads it as it is (only a partial last segment is padded on the
+host); the device byte-swaps and joins it into
 (S, segment, 20) big-endian uint32 words and runs PoW + prev-hash linkage
 as a single batched device program — a ``lax.scan`` over segments with
 the cross-segment digest carried on device
@@ -74,8 +75,9 @@ def generate_headers(
 
 def pack_headers(headers: list[BlockHeader]) -> bytes:
     """The contiguous (N*80)-byte buffer of the headers' canonical
-    encodings — ONE packer shared by the native, device, and export
-    planes.  ``BlockHeader.serialize`` memoizes, so for headers a node
+    encodings — the packer of the native and export planes (the device
+    rung gathers the same bytes into an array, ``pack_header_rows``).
+    ``BlockHeader.serialize`` memoizes, so for headers a node
     already holds (ingested off the wire, or serialized once before)
     this is a join of cached buffers: no per-header struct packing, which
     is what closes replay-from-objects toward the raw-bytes rate."""
@@ -86,6 +88,36 @@ def pack_headers(headers: list[BlockHeader]) -> bytes:
     except AttributeError:
         # Some header not yet encoded: pay its one-time pack.
         return b"".join([h.serialize() for h in headers])
+
+
+def _gather_rows(headers: list[BlockHeader]) -> tuple[np.ndarray, int]:
+    """``pack_header_rows``, and how many headers had no cached encoding
+    (each serialized here, once)."""
+    n = len(headers)
+    width = f"S{HEADER_SIZE}"
+    try:
+        # One C-level pass copies each memoized encoding into NumPy's own
+        # allocation: no intermediate ``bytes``, nothing kept between calls.
+        rows = np.fromiter(map(operator.attrgetter("_raw"), headers), width, count=n)
+        serialized = 0
+    except AttributeError:
+        serialized = sum(not hasattr(h, "_raw") for h in headers)
+        rows = np.fromiter(
+            map(operator.methodcaller("serialize"), headers), width, count=n
+        )
+    # "<u4", not the native uint32: the device's swap is right on any host.
+    return rows.view("<u4").reshape(n, HEADER_SIZE // 4), serialized
+
+
+def pack_header_rows(headers: list[BlockHeader]) -> np.ndarray:
+    """The headers' canonical encodings as one C-contiguous (N, 20)
+    little-endian uint32 array, byte for byte ``pack_headers``' buffer:
+    the device rung's packer.  A fixed-width ``np.fromiter`` over the
+    cached encodings fills the array directly, where ``pack_headers``
+    joins them into a fresh ``bytes`` first (trailing NUL bytes are kept,
+    as the width is fixed).  A header with no cached encoding is
+    serialized, which caches it."""
+    return _gather_rows(headers)[0]
 
 
 def parse_headers(raw: bytes) -> list[BlockHeader]:
@@ -304,9 +336,12 @@ def replay_device(
 ) -> ReplayReport:
     """Whole-chain device verification in ONE scan dispatch.
 
-    The host packs the chain (``pack_headers``) and uploads the packed
-    buffer as it is: its full segments as a zero-copy (S_full, segment, 20)
-    little-endian view, and, when ``n`` is no multiple of ``segment``, one
+    The host gathers the header objects into one (n, 20) little-endian
+    array (``pack_header_rows``, inside the profiler span
+    ``p1.replay.pack`` with attributes ``headers`` and ``serialized``, the
+    headers that had no cached encoding) and uploads it as it is: its full
+    segments as a zero-copy (S_full, segment, 20) view, and, when ``n`` is
+    no multiple of ``segment``, one
     tail segment of the last real headers padded with byte-copies of the
     last header (the only rows the host copies, at most one segment).  A
     small jitted staging program byte-swaps the words to big-endian and
@@ -329,6 +364,7 @@ def replay_device(
 
     from p1_tpu.core.header import target_from_difficulty, target_to_words
     from p1_tpu.hashx import configure_compile_cache
+    from p1_tpu.hashx.jax_backend import step_span_maker
     from p1_tpu.hashx.jax_sha256 import jit_verify_chain_scan
 
     configure_compile_cache()
@@ -340,25 +376,21 @@ def replay_device(
     target = jnp.asarray(
         target_to_words(target_from_difficulty(difficulty)), jnp.uint32
     )
-    raw = pack_headers(headers)
     n = len(headers)
     n_body = n // segment
     n_segments = -(-n // segment)
     parts = []
-    if n_body:
-        # "<u4", not the native uint32: the device's swap is right on any host.
-        parts.append(
-            np.frombuffer(raw, "<u4", count=n_body * segment * 20).reshape(
-                n_body, segment, 20
-            )
-        )
-    if n_segments > n_body:
-        offset = n_body * segment * HEADER_SIZE
-        rows = np.frombuffer(raw, "<u4", offset=offset).reshape(-1, 20)
-        tail = np.empty((1, segment, 20), "<u4")
-        tail[0, : len(rows)] = rows
-        tail[0, len(rows) :] = rows[-1]
-        parts.append(tail)
+    with step_span_maker()("p1.replay.pack", headers=n) as span:
+        rows, serialized = _gather_rows(headers)
+        span.set_metadata(serialized=serialized)
+        if n_body:
+            parts.append(rows[: n_body * segment].reshape(n_body, segment, 20))
+        if n_segments > n_body:
+            last = rows[n_body * segment :]
+            tail = np.empty((1, segment, 20), "<u4")
+            tail[0, : len(last)] = last
+            tail[0, len(last) :] = last[-1]
+            parts.append(tail)
     words3 = _jit_stage(platform)(*parts)
     step = jit_verify_chain_scan(n_segments, segment, platform)
 

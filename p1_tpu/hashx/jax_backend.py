@@ -97,14 +97,30 @@ class _Launch(NamedTuple):
         return self.result
 
 
-_NO_SPAN = contextlib.nullcontext()
+class _NoSpan(contextlib.nullcontext):
+    """``TraceAnnotation``'s stand-in while step spans are off, down to
+    ``set_metadata``.  (With no profiler trace recording,
+    ``TraceAnnotation`` itself is a native no-op, cheaper than this.)"""
+
+    def __enter__(self):
+        return self
+
+    def set_metadata(self, **metadata) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
 
 
 def _no_span(name: str, **metadata):
-    """``TraceAnnotation``'s stand-in while step spans are off.  (With no
-    profiler trace recording, ``TraceAnnotation`` itself is a native
-    no-op, cheaper than this.)"""
     return _NO_SPAN
+
+
+def step_span_maker():
+    """How this thread's device steps open their ``p1.*`` spans:
+    ``TraceAnnotation`` while step spans are on (``backend.step_spans``),
+    else a stand-in that writes nothing."""
+    return TraceAnnotation if step_spans_on() else _no_span
 
 
 class PipelinedSearchMixin:
@@ -187,7 +203,7 @@ class PipelinedSearchMixin:
         self, header_prefix: bytes, nonce_start: int, count: int, difficulty: int
     ) -> SearchResult:
         self._check_search_args(header_prefix, nonce_start, count, difficulty)
-        span_of = TraceAnnotation if step_spans_on() else _no_span
+        span_of = step_span_maker()
         steps = StepCounts()
         t = time.perf_counter()
         with span_of("p1.search.prepare"):

@@ -434,6 +434,58 @@ class TestReplay:
         device = replay_device(bad, segment=16)
         assert host.first_invalid == device.first_invalid == 21
 
+    @pytest.mark.parametrize("kind", ["parsed", "fresh", "mixed", "nul_tail"])
+    def test_pack_header_rows_matches_pack_headers(self, headers, kind):
+        # Parsed headers carry their cached encoding; fresh ones (no
+        # ``_raw``) take the serializing fallback; a nonce-0 header's
+        # encoding ends in NUL bytes, which the fixed-width gather keeps.
+        import dataclasses as dc
+
+        import numpy as np
+
+        from p1_tpu.chain import pack_header_rows, pack_headers, parse_headers
+
+        parsed = parse_headers(b"".join(h.serialize() for h in headers))
+        fresh = [dc.replace(h) for h in headers]
+        chain = {
+            "parsed": parsed,
+            "fresh": fresh,
+            "mixed": [p if i % 3 else f for i, (p, f) in enumerate(zip(parsed, fresh))],
+            "nul_tail": parse_headers(
+                b"".join(h.with_nonce(n).serialize() for h, n in zip(headers, (0, 256) * 4))
+            ),
+        }[kind]
+        cached = [hasattr(h, "_raw") for h in chain]
+        assert {"fresh": not any(cached), "mixed": 0 < sum(cached) < len(chain)}.get(
+            kind, all(cached)
+        )
+        rows = pack_header_rows(chain)
+        assert rows.shape == (len(chain), 20) and rows.dtype == np.dtype("<u4")
+        assert rows.flags.c_contiguous
+        assert rows.tobytes() == pack_headers(chain)
+        if kind == "nul_tail":
+            assert all(h.serialize().endswith(b"\0") for h in chain)
+
+    @pytest.mark.parametrize("rest", ["parsed", "fresh"])
+    def test_device_matches_host_on_a_deserialized_corruption(self, headers, rest):
+        # As the benchmark's ``compare`` does: one mid-chain header object
+        # swapped for ``BlockHeader.deserialize`` of its flipped bytes, the
+        # rest cached (parsed) or not yet encoded (the gather's fallback).
+        import dataclasses as dc
+
+        from p1_tpu.chain import parse_headers
+
+        if rest == "parsed":
+            chain = parse_headers(b"".join(h.serialize() for h in headers))
+        else:
+            chain = [dc.replace(h) for h in headers]
+        raw = bytearray(headers[37].serialize())
+        raw[4 + 9] ^= 0x10  # a prev_hash bit
+        chain[37] = BlockHeader.deserialize(bytes(raw))
+        host = replay_host(chain)
+        device = replay_device(chain, segment=24)
+        assert host.first_invalid == device.first_invalid == 37
+
     def test_partial_segment_padding(self, headers):
         # 64 headers with segment 24 -> final segment is 16 real + 8 pad.
         report = replay_device(headers, segment=24)
@@ -527,6 +579,13 @@ class TestReplay:
         )
         assert (rows[40:] == rows[39]).all()
 
+        # Headers with no cached encoding (the gather's fallback) give the
+        # scan the same words.
+        import dataclasses as dc
+
+        assert replay_device([dc.replace(h) for h in chain], segment=16).valid
+        np.testing.assert_array_equal(seen[1], words3)
+
         # Another length with the same segment count compiles nothing new.
         compiles = []
 
@@ -539,7 +598,7 @@ class TestReplay:
             assert replay_device(headers[:36], segment=16).valid
         finally:
             jax.monitoring.unregister_event_duration_listener(count)
-        assert len(seen) == 2 and seen[1].shape == (3, 16, 20)
+        assert len(seen) == 3 and seen[2].shape == (3, 16, 20)
         assert compiles == []
 
 

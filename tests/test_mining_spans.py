@@ -242,6 +242,39 @@ class TestNodeTrace:
         assert snap["histograms"] == {}
 
 
+class TestReplayPackSpan:
+    """``replay_device`` wraps its gather of the header objects in one
+    ``p1.replay.pack`` span a call: the headers gathered, and how many had
+    no cached encoding and were serialized."""
+
+    @pytest.fixture(scope="class")
+    def parsed(self):
+        from p1_tpu.chain import generate_headers, parse_headers
+
+        return parse_headers(b"".join(h.serialize() for h in generate_headers(20, 4)))
+
+    def test_pack_span_counts_headers_and_fallback(self, tmp_path, parsed):
+        import dataclasses
+
+        from p1_tpu.chain import replay_device
+
+        fresh = [dataclasses.replace(h) for h in parsed[:7]] + parsed[7:]
+        events = _capture(tmp_path, lambda: [
+            replay_device(chain, segment=8) for chain in (parsed, fresh)])
+        packs = [e[3] for e in events if e[0] == "p1.replay.pack"]
+        assert packs == [{"headers": 20, "serialized": 0}, {"headers": 20, "serialized": 7}]
+
+    def test_step_spans_off_writes_no_pack_span(self, tmp_path, parsed):
+        from p1_tpu.chain import replay_device
+        from p1_tpu.hashx.backend import step_spans
+
+        def body():
+            with step_spans(False):
+                assert replay_device(parsed, segment=8).valid
+
+        assert _capture(tmp_path, body) == []
+
+
 def _pool_file(store: str, difficulty: int, senders: int = 4, per: int = 6,
                unaffordable: int = 2) -> tuple[int, int]:
     """Write ``<store>.mempool``: ``senders * per`` zero-value transfers,
